@@ -10,16 +10,13 @@ slot decode engine with plan-driven sparse MoE dispatch (DESIGN.md §8).
 through the adaptive engine and prints throughput, wire bytes, and the
 sparse<->dense dispatch swaps the telemetry drove.
 """
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
 import time
 
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.config import ModelConfig
 from repro.models.model import build_model
@@ -35,7 +32,8 @@ import jax.numpy as jnp
 
 
 def build(fast: bool):
-    mesh = make_host_mesh(data=4, model=2)
+    # experts over a 2-wide 'model' axis where the device count allows
+    mesh = make_host_mesh(model=2 if len(jax.devices()) % 2 == 0 else 1)
     kw = dict(num_layers=2, d_model=128, d_ff=256) if fast else \
         dict(num_layers=4, d_model=256, d_ff=512)
     cfg = ModelConfig(name="serve-demo", family="moe", num_heads=8,
@@ -149,6 +147,7 @@ def main():
                          "complete via pre-dispatch tick retries "
                          "(implies --continuous)")
     args = ap.parse_args()
+    enable_compile_cache()
     tokens = args.tokens if args.tokens is not None else (8 if args.fast else 24)
 
     from repro import obs as obs_mod

@@ -17,16 +17,15 @@ A crash / Ctrl-C mid-run resumes from the latest checkpoint on restart
 (same command). ~100M x 300 steps is a few hours on this small CPU
 container; --fast demonstrates the identical code path in ~2 minutes.
 """
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
+import os
+import tempfile
 
 import jax.numpy as jnp
 
 from repro.core.compressor import SyncConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.config import ModelConfig
 from repro.models.model import build_model
@@ -40,7 +39,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--ckpt-dir", type=str, default="/tmp/sparcml_lm_ckpt")
+    ap.add_argument("--ckpt-dir", type=str, default=os.path.join(
+        tempfile.gettempdir(), "sparcml_lm_ckpt"))
     ap.add_argument("--pipeline", action="store_true",
                     help="non-blocking runtime: pipelined stale-gradient "
                          "supersteps + async driver (DESIGN.md §6)")
@@ -79,6 +79,7 @@ def main():
                          "guarded step + retry/backoff recovery (implies "
                          "--pipeline)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro import obs as obs_mod
 
@@ -124,7 +125,7 @@ def main():
         microbatches=2,
         zero1=True,
     )
-    mesh = make_host_mesh(data=4, model=2)
+    mesh = make_host_mesh()
     if args.zero:
         from repro.launch.dryrun import state_memory_breakdown
 

@@ -2,16 +2,16 @@
 
 Inside the training step the data-parallel axes are MANUAL (shard_map)
 while 'model' stays AUTO so XLA keeps inserting the tensor-parallel
-collectives. On some backends (XLA-CPU in the pinned container build)
-the SPMD partitioner hard-aborts on every explicit collective except
-``psum`` when lowered in such a partial-manual region. The
+collectives. An older XLA-CPU partitioner hard-aborted on every explicit
+collective except ``psum`` lowered in such a partial-manual region. The
 :class:`CollectiveContext` therefore carries a ``native`` switch:
 
-* native=True  — ``jax.lax`` collectives (TPU, or fully-manual regions);
+* native=True  — ``jax.lax`` collectives (the train step's lowering);
 * native=False — the same semantics built from ONE psum each: the rank
   writes its contribution into a zero buffer at its slot and the psum
   concatenates. Wire volume is that of a dense allreduce — correctness
-  scaffolding for hosts where the partitioner is broken, not a fast path.
+  scaffolding that tests force (``lowering="emulated"``), not a fast
+  path.
 
 The emulated path cannot use ``jax.lax.axis_index`` (PartitionId is also
 unsupported there), so the rank arrives as DATA: a (1,) int32 slice of a

@@ -1,109 +1,35 @@
-"""JAX version-compatibility shims.
+"""Mesh and shard_map helpers over the installed JAX.
 
-The repo targets the current JAX surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``); pinned
-container builds may ship an older JAX where those names do not exist.
-Every version-dependent import lives HERE — library code, tests, and
-benchmarks import :func:`make_mesh` / :func:`shard_map` from this module
-(or via ``repro.launch.mesh``) instead of touching ``jax.*`` directly.
+Library code, tests and benchmarks build meshes and manual regions
+through here (or via ``repro.launch.mesh``), so axis types and the
+shard_map calling convention are set in one place.
 
 Nothing in this module touches device state at import time.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
-
-try:  # new builds
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # old builds: make_mesh has no axis_types kwarg at all
-    AxisType = None
-
-HAS_AXIS_TYPE = AxisType is not None
-HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` with ``AxisType.Auto`` axes where supported.
-
-    Older builds have no axis-type concept; plain meshes behave identically
-    for every use in this repo (explicit shard_map manual/auto sets are
-    passed separately — see :func:`shard_map`).
-    """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (manual axes
+    are chosen per region — see :func:`shard_map`)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names: Optional[set] = None):
-    """Signature adapter over ``jax.shard_map`` / legacy experimental API.
+    """``jax.shard_map`` with ``check_vma`` off by default.
 
     axis_names: the MANUAL axes; every other mesh axis stays auto (XLA
     keeps inserting its collectives for them). None = manual over all axes.
-    check_vma maps to the legacy ``check_rep``.
     """
-    if HAS_JAX_SHARD_MAP:
-        kw: dict[str, Any] = dict(mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto: frozenset = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
-
-
-def ambient_mesh_shape() -> dict:
-    """{axis: size} of the mesh currently in scope (trace-time), {} when
-    none. New builds expose jax.sharding.get_abstract_mesh; old builds
-    track the ambient mesh in thread resources."""
-    try:
-        from jax.sharding import get_abstract_mesh  # type: ignore
-
-        return dict(get_abstract_mesh().shape)
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        return {} if m is None or m.empty else dict(m.shape)
-    except Exception:
-        return {}
-
-
-def cost_analysis(compiled) -> dict:
-    """Compiled.cost_analysis() as a flat dict on every JAX version
-    (older builds return a one-element list of dicts per program)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
-
-
-def partial_manual_collectives_broken(mesh, manual_axes) -> bool:
-    """True when explicit collectives other than psum abort inside a
-    PARTIAL-manual shard_map on this backend.
-
-    The XLA-CPU SPMD partitioner in older builds hard-aborts (CHECK
-    failure on manual subgroups) for all_to_all / all_gather / ppermute
-    lowered inside a shard_map that leaves some mesh axes auto; psum is
-    the one collective that survives. Real TPU backends are fine, and
-    FULLY-manual regions are fine everywhere. The comm executor swaps in
-    psum-emulated collectives when this returns True (DESIGN.md §4).
-    """
-    auto_axes = set(mesh.axis_names) - set(manual_axes)
-    if all(mesh.shape[a] == 1 for a in auto_axes):
-        # Fully manual (or trivially-auto: size-1 axes create no real
-        # subgroup partitioning): native collectives always work.
-        return False
-    return jax.default_backend() == "cpu" and not HAS_JAX_SHARD_MAP
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from repro.core import sparse_stream as ss
 from repro.core.sparse_stream import SENTINEL, SparseStream
-from repro.core.topk import UniformStream, _topk_lowers_everywhere
+from repro.core.topk import UniformStream
 from repro.core.qsgd import QSGDConfig, quantize, dequantize
 from repro.core.cost_model import (
     balanced_shard_cap,
@@ -201,13 +201,10 @@ def ssar_split_allgather_inside(
 
 
 def _top_cap_indices(mag: jax.Array, cap: int) -> jax.Array:
-    """Indices of the ``cap`` largest magnitudes (ties -> lower index;
-    both lax.top_k and a stable descending argsort break ties that way,
-    so every rank picks deterministically whatever the lowering)."""
-    if _topk_lowers_everywhere():
-        _, idx = jax.lax.top_k(mag, cap)
-        return idx
-    return jnp.argsort(-mag, stable=True)[:cap]
+    """Indices of the ``cap`` largest magnitudes (ties -> lower index,
+    so every rank picks deterministically)."""
+    _, idx = jax.lax.top_k(mag, cap)
+    return idx
 
 
 def _take_top_stream(s: SparseStream, mask: jax.Array, cap: int):

@@ -18,16 +18,6 @@ from repro.kernels.bucket_scatter.ops import bucket_scatter
 from repro.core.sparse_stream import SparseStream
 
 
-def _topk_lowers_everywhere() -> bool:
-    """lax.top_k is the fast path; the pinned old-JAX XLA-CPU build
-    aborts on its partitioner rule in partial-manual regions (and
-    compress2d cannot know its lowering context), so that build takes
-    the argsort fallback globally."""
-    from repro import compat
-
-    return compat.HAS_JAX_SHARD_MAP or jax.default_backend() != "cpu"
-
-
 class UniformStream(NamedTuple):
     """A bucket-uniform sparse vector: exactly k entries per B-wide bucket.
 
@@ -124,9 +114,9 @@ def compress2d(
 ) -> tuple[BatchedStream, jax.Array]:
     """Batched TopK compression of a canonical (*lead, cols) layout.
 
-    Pure batched-jnp (sort/take_along_axis operate on the last axis
-    only — the leading dims are never merged or split), so every leading
-    axis keeps whatever sharding it has. Returns
+    Pure batched-jnp (top_k/sort/take_along_axis operate on the last
+    axis only — the leading dims are never merged or split), so every
+    leading axis keeps whatever sharding it has. Returns
     (stream, residual (*lead, cols))."""
     *lead, cols = x.shape
     b = bucket_size
@@ -134,15 +124,7 @@ def compress2d(
     m = cols // b
     xb = x.reshape(*lead, m, b)
     mag = jnp.abs(xb)
-    if _topk_lowers_everywhere():
-        _, order = jax.lax.top_k(mag, k_per_bucket)          # (*lead, m, k)
-    else:
-        # Stable argsort fallback: identical selection (ties go to the
-        # lower index, same as top_k), but top_k's partitioner rule
-        # aborts in partial-manual regions on the pinned XLA-CPU build
-        # while sort lowers fine everywhere (DESIGN.md §5.2). O(B log B)
-        # vs O(B) — paid only on the correctness backend.
-        order = jnp.argsort(-mag, axis=-1)[..., :k_per_bucket]
+    _, order = jax.lax.top_k(mag, k_per_bucket)              # (*lead, m, k)
     lidx = jnp.sort(order, axis=-1).astype(jnp.int32)
     val = jnp.take_along_axis(xb, lidx, axis=-1)
     iota = jnp.arange(b, dtype=jnp.int32)

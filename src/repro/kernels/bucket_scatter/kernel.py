@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import row_tile
+
 
 def _kernel(lidx_ref, val_ref, out_ref):
     lidx = lidx_ref[...]  # (TB, k)
@@ -36,12 +38,10 @@ def bucket_scatter_pallas(
 ):
     nb, k = lidx.shape
     if tb is None:
-        tb = max(1, min(nb, (2 * 1024 * 1024 // 4) // max(1, k * b)))
-        while nb % tb:
-            tb -= 1
+        tb = row_tile(nb, (2 * 1024 * 1024 // 4) // max(1, k * b))
     return pl.pallas_call(
         _kernel,
-        grid=(nb // tb,),
+        grid=(pl.cdiv(nb, tb),),
         in_specs=[
             pl.BlockSpec((tb, k), lambda i: (i, 0)),
             pl.BlockSpec((tb, k), lambda i: (i, 0)),

@@ -4,8 +4,10 @@ One grid step processes TB buckets (rows). Working set per step:
   x tile (TB, B) + magnitude copy + one-hot accumulation -> ~3*TB*B*4 bytes
 kept well under VMEM (16 MB). B is a multiple of 128 (lane width) and the
 selection loop is unrolled k times (k is small: 2..64), each iteration one
-row-argmax on the VPU followed by a compare-select; there is no serialized
-scatter anywhere — TPU-native by construction.
+row max and one row min on the VPU followed by a compare-select; a second
+unrolled loop reads the k selected indices out in ascending order. There
+is no serialized scatter and no sort anywhere — TPU-native by
+construction.
 """
 from __future__ import annotations
 
@@ -14,6 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tiling import row_tile
 
 NEG_INF = float("-inf")  # plain python float: not captured as a traced const
 
@@ -24,21 +28,28 @@ def _kernel(x_ref, val_ref, lidx_ref, res_ref, *, k: int):
     mag = jnp.abs(x)
     iota = jax.lax.broadcasted_iota(jnp.int32, (tb, b), 1)
     sel = jnp.zeros((tb, b), jnp.bool_)
-    idxs = []
-    # Unrolled iterative argmax: identical tie-break (lowest index) as
-    # jax.lax.top_k in ref.py.
+    # Unrolled iterative argmax, written as max then lowest index at the
+    # max: the same tie-break (lowest index) as jax.lax.top_k in ref.py.
     for _ in range(k):
-        j = jnp.argmax(mag, axis=1).astype(jnp.int32)  # (TB,)
-        hit = iota == j[:, None]  # (TB, B) one-hot
+        top = jnp.max(mag, axis=1, keepdims=True)
+        j = jnp.min(jnp.where(mag == top, iota, b), axis=1, keepdims=True)
+        hit = iota == j  # (TB, B) one-hot
         sel = sel | hit
         mag = jnp.where(hit, NEG_INF, mag)
-        idxs.append(j)
-    lidx = jnp.stack(idxs, axis=1)  # (TB, k) in selection order
-    # Reorder by ascending local index (cheap k*log k on rows of length k).
-    lidx = jnp.sort(lidx, axis=1)
-    # Gather selected values with one-hot contractions (k small).
-    onehot = (lidx[:, :, None] == iota[:, None, :]).astype(x.dtype)  # (TB,k,B)
-    val = jnp.sum(onehot * x[:, None, :], axis=2)  # (TB, k)
+    # Read the selection out in ascending index order: the i-th output is
+    # the lowest selected index not yet read. No in-kernel sort (the TPU
+    # kernel compiler has none); values come out by one-hot reduction.
+    slot = jax.lax.broadcasted_iota(jnp.int32, (tb, k), 1)
+    lidx = jnp.zeros((tb, k), jnp.int32)
+    val = jnp.zeros((tb, k), jnp.float32)
+    left = sel
+    for i in range(k):
+        j = jnp.min(jnp.where(left, iota, b), axis=1, keepdims=True)
+        hit = iota == j
+        v = jnp.sum(jnp.where(hit, x, 0.0), axis=1, keepdims=True)
+        lidx = jnp.where(slot == i, j, lidx)
+        val = jnp.where(slot == i, v, val)
+        left = left & ~hit
     val_ref[...] = val.astype(val_ref.dtype)
     lidx_ref[...] = lidx
     res_ref[...] = jnp.where(sel, 0, x_ref[...])
@@ -49,10 +60,8 @@ def bucket_topk_pallas(x: jax.Array, k: int, *, interpret: bool = True, tb: int 
     nb, b = x.shape
     if tb is None:
         # Target ~64K elements of x per grid step.
-        tb = max(1, min(nb, 65536 // b))
-        while nb % tb:
-            tb -= 1
-    grid = (nb // tb,)
+        tb = row_tile(nb, 65536 // b)
+    grid = (pl.cdiv(nb, tb),)
     return pl.pallas_call(
         functools.partial(_kernel, k=k),
         grid=grid,

@@ -8,20 +8,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.qsgd_pack.ref import levels
+from repro.kernels.tiling import row_tile
 
 
 def _kernel(packed_ref, scale_ref, out_ref, *, bits: int):
-    packed = packed_ref[...]  # (TB, W) uint32
-    tb, w = packed.shape
+    # Planar layout (qsgd_pack/ref.py): field j of every word fills the
+    # lane slice [j*W, (j+1)*W) of the bucket.
+    packed = jax.lax.bitcast_convert_type(packed_ref[...], jnp.int32)
+    w = packed.shape[1]
     vpw = 32 // bits
     s = levels(bits)
-    mask = jnp.uint32(2**bits - 1)
-    shifts = (jax.lax.broadcasted_iota(jnp.uint32, (tb, w, vpw), 2)
-              * jnp.uint32(bits))
-    biased = (packed[:, :, None] >> shifts) & mask
-    code = biased.astype(jnp.int32) - s
-    xhat = code.astype(jnp.float32) / s * scale_ref[...][:, :, None]
-    out_ref[...] = xhat.reshape(tb, w * vpw).astype(out_ref.dtype)
+    mask = 2**bits - 1
+    scale = scale_ref[...]  # (TB, 1)
+    for j in range(vpw):
+        # the arithmetic shift's sign bits fall outside the mask
+        code = ((packed >> (j * bits)) & mask) - s
+        xhat = code.astype(jnp.float32) / s * scale
+        out_ref[:, j * w:(j + 1) * w] = xhat.astype(out_ref.dtype)
 
 
 def qsgd_unpack_pallas(
@@ -37,12 +40,10 @@ def qsgd_unpack_pallas(
     vpw = 32 // bits
     bq = w * vpw
     if tb is None:
-        tb = max(1, min(nb, 65536 // bq))
-        while nb % tb:
-            tb -= 1
+        tb = row_tile(nb, 65536 // bq)
     return pl.pallas_call(
         functools.partial(_kernel, bits=bits),
-        grid=(nb // tb,),
+        grid=(pl.cdiv(nb, tb),),
         in_specs=[
             pl.BlockSpec((tb, w), lambda i: (i, 0)),
             pl.BlockSpec((tb, 1), lambda i: (i, 0)),

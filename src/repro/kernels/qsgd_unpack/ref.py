@@ -12,8 +12,8 @@ def qsgd_unpack_ref(packed: jax.Array, scale: jax.Array, bits: int, out_dtype=jn
     vpw = 32 // bits
     s = levels(bits)
     mask = jnp.uint32(2**bits - 1)
-    shifts = (jnp.arange(vpw, dtype=jnp.uint32) * bits)[None, None, :]
-    biased = (packed[:, :, None] >> shifts) & mask  # (nb, w, vpw)
+    shifts = (jnp.arange(vpw, dtype=jnp.uint32) * bits)[None, :, None]
+    biased = (packed[:, None, :] >> shifts) & mask  # (nb, vpw, w): planar
     code = biased.astype(jnp.int32) - s
     xhat = code.astype(jnp.float32) / s * scale[:, :, None]
-    return xhat.reshape(nb, w * vpw).astype(out_dtype)
+    return xhat.reshape(nb, vpw * w).astype(out_dtype)

@@ -4,12 +4,12 @@ A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (smoke tests see 1 CPU device; only dryrun.py
 sets the 512-placeholder-device XLA flag before any jax import).
 
-All meshes are built through :func:`repro.compat.make_mesh`, which applies
-``AxisType.Auto`` on JAX builds that support axis types and silently omits
-it elsewhere — tests, benchmarks, and examples route through here so no
-other module imports ``jax.sharding.AxisType`` directly.
+All meshes are built through :func:`repro.compat.make_mesh`, which gives
+every axis ``AxisType.Auto``.
 """
 from __future__ import annotations
+
+import jax
 
 from repro.compat import make_mesh
 
@@ -21,8 +21,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 4, model: int = 2, pod: int = 0):
-    """Small mesh over host devices (tests / examples)."""
+def make_host_mesh(data: int | None = None, model: int = 1, pod: int = 0):
+    """Mesh over this host's devices. ``data`` defaults to every device
+    the host has, over ``model`` x ``pod``: ``(len(jax.devices()), 1)``
+    with no sizes given, so one chip gets a ``(1, 1)`` mesh and a
+    four-chip host a ``(4, 1)`` one."""
+    if data is None:
+        data = len(jax.devices()) // (model * max(pod, 1))
     if pod:
         return make_mesh((pod, data, model), ("pod", "data", "model"))
     return make_mesh((data, model), ("data", "model"))

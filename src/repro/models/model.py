@@ -170,11 +170,10 @@ def _cb(x, cfg: ModelConfig):
     """
     if not cfg.act_dp_axes:
         return x
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
-    from repro.compat import ambient_mesh_shape
     axes = list(cfg.act_dp_axes)
-    mesh_shape = ambient_mesh_shape()
+    mesh_shape = dict(get_abstract_mesh().shape)
     # drop leading dp axes until the batch dim divides evenly (microbatches
     # can be narrower than pod x data)
     import numpy as _np

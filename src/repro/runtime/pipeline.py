@@ -30,9 +30,10 @@ parameters are untouched until a real reduction lands (the optimizer's
 count still advances and its moments decay once — a one-step offset,
 negligible and documented).
 
-Three lowerings, mirroring ``train_step`` (DESIGN.md §4): ``manual``
-(shard_map + native collectives), ``emulated`` (shard_map + psum-emulated
-collectives), ``spmd`` (auto-SPMD, no shard_map). ``build_superstep``
+Three lowerings (DESIGN.md §4): ``manual`` (shard_map + native
+collectives, ``train_step``'s and the default), ``emulated`` (shard_map +
+psum-emulated collectives), ``spmd`` (auto-SPMD, no shard_map); tests
+force the last two. ``build_superstep``
 wraps the step in a jitted ``lax.scan`` over K steps, so one dispatch
 covers a whole superstep and the per-step jaxpr keeps O(num_buckets)
 collectives.
@@ -63,11 +64,12 @@ LOWERINGS = ("manual", "emulated", "spmd")
 VALID_KEY = "__valid__"
 
 
-def resolve_lowering(mesh: Mesh, lowering: Optional[str] = None) -> str:
-    """Default to the same backend detection as build_train_step; tests
-    force a specific lowering to assert cross-lowering parity."""
+def resolve_lowering(lowering: Optional[str] = None) -> str:
+    """Default to build_train_step's lowering (manual, native
+    collectives); tests force the others to assert cross-lowering
+    parity."""
     if lowering is None:
-        return "manual" if ts.sparcml_uses_manual_collectives(mesh) else "spmd"
+        return "manual"
     if lowering not in LOWERINGS:
         raise ValueError(f"lowering must be one of {LOWERINGS}: {lowering!r}")
     return lowering
@@ -178,7 +180,7 @@ def _make_raw_step(model: Model, tcfg: TrainConfig, mesh: Mesh,
     the reduce half, so an all-zero vector is bit-exact with no injector."""
     cfg = model.cfg
     sched = make_schedule(tcfg.schedule)
-    lowering = resolve_lowering(mesh, lowering)
+    lowering = resolve_lowering(lowering)
     shapes, specs, plan = pipelined_state_shapes(model, tcfg, mesh,
                                                  staleness=staleness,
                                                  plan=plan)

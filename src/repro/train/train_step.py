@@ -589,14 +589,6 @@ def _zero_scattered_update_spmd(params, grads, opt, lr, tcfg: TrainConfig,
     return ptree.unflatten(new_leaves), out_opt
 
 
-def sparcml_uses_manual_collectives(mesh: Mesh) -> bool:
-    """True when the sparcml step lowers through the shard_map manual-dp
-    region (native collectives: all-to-all/all-gather appear in HLO);
-    False when it falls back to the auto-SPMD formulation (XLA inserts
-    all-reduces — DESIGN.md §4.2)."""
-    return not compat.partial_manual_collectives_broken(mesh, dp_axes_of(mesh))
-
-
 def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
     """Returns (jitted step fn(state, batch, key) -> (state, metrics),
     (state_shapes, state_specs))."""
@@ -635,89 +627,14 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
         return jitted, (shapes, specs)
 
     # ---------------- sparcml mode: manual dp, auto model ----------------
+    # Native lax collectives inside the manual-dp shard_map region
+    # (DESIGN.md §4); `plan` is the one state_shapes keyed the residual
+    # dict with.
     pspecs = specs.params
-    # `plan` is the one state_shapes keyed the residual dict with.
-    # Collective flavor inside the partial-manual region (DESIGN.md §4):
-    # native lax collectives, or the psum-emulated fallback on backends
-    # whose partitioner cannot lower them there (XLA-CPU container build).
-    native = not compat.partial_manual_collectives_broken(mesh, dp_ax)
     data_axis = dp_ax[-1]
     p_data = mesh.shape[data_axis]
     pod_axis = dp_ax[0] if len(dp_ax) > 1 else None
     p_pod = mesh.shape[pod_axis] if pod_axis else 1
-
-
-    if not native:
-        # ------- auto-SPMD fallback: no shard_map (DESIGN.md §4.2) -------
-        # The partitioner of this backend cannot lower a partial-manual
-        # region (scan bodies / non-psum collectives abort), so the
-        # replica axis becomes a real leading axis: vmap computes every
-        # rank's grads on its batch slice, the executor's sums over that
-        # axis ARE the allreduce (XLA inserts them), numerics unchanged.
-        def step_fn(state: TrainState, batch, key):
-            lr = sched(state.step)
-
-            def split_ranks(x):
-                out = x.reshape((dp_total, x.shape[0] // dp_total)
-                                + x.shape[1:])
-                spec = P(tuple(dp_ax), *([None] * (out.ndim - 1)))
-                return jax.lax.with_sharding_constraint(
-                    out, NamedSharding(mesh, spec))
-
-            batch_r = jax.tree.map(split_ranks, batch)
-            loss_r, grads_r = jax.vmap(
-                lambda b: _accumulated_grads(model, state.params, b,
-                                             n_micro))(batch_r)
-            loss = jnp.mean(loss_r)
-            leaves_r, gtree = jax.tree.flatten(grads_r)
-            leaves_spec = gtree.flatten_up_to(pspecs)
-            leaves_r = [
-                jax.lax.with_sharding_constraint(
-                    g, NamedSharding(mesh, P(tuple(dp_ax),
-                                             *(s if s is not None else ()))))
-                for g, s in zip(leaves_r, leaves_spec)
-            ]
-            if plan.scattered:
-                # Scattered (DESIGN.md §11): owner chunks in, shard
-                # update, chunk->buffer rebuild. The clip reuses the
-                # replicated code path on the rebuilt leaves so the
-                # factor — and therefore training — is BIT-identical.
-                reduced, new_res, _ = comm.reduce_buckets_spmd(
-                    plan, leaves_r, state.residuals, key,
-                    p_data=p_data, p_pod=p_pod)
-                synced_leaves = comm.apply_buckets_spmd(
-                    plan, comm.unchunk_buckets_spmd(plan, reduced), leaves_r)
-                synced = gtree.unflatten(synced_leaves)
-                synced, gnorm = clip_by_global_norm(
-                    synced, tcfg.optimizer.grad_clip)
-                new_p, new_opt = _zero_scattered_update_spmd(
-                    state.params, synced, state.opt, lr, tcfg, plan)
-                new_state = TrainState(new_p, new_opt, new_res,
-                                       state.step + 1)
-                return new_state, {"loss": loss, "grad_norm": gnorm,
-                                   "lr": lr}
-            synced_leaves, new_res = comm.execute_plan_spmd(
-                plan, leaves_r, state.residuals, key,
-                p_data=p_data, p_pod=p_pod)
-            synced = gtree.unflatten(synced_leaves)
-            synced, gnorm = clip_by_global_norm(synced, tcfg.optimizer.grad_clip)
-            if tcfg.zero1:
-                new_p, new_opt = _zero1_update_spmd(
-                    state.params, synced, state.opt, lr, tcfg, pspecs,
-                    dp_total)
-            else:
-                new_p, new_opt = opt_update(
-                    state.params, synced, state.opt, lr, tcfg.optimizer)
-            new_state = TrainState(new_p, new_opt, new_res, state.step + 1)
-            return new_state, {"loss": loss, "grad_norm": gnorm, "lr": lr}
-
-        jitted = jax.jit(
-            step_fn,
-            in_shardings=(sh(specs), sh(bspecs), NamedSharding(mesh, P())),
-            out_shardings=(sh(specs), NamedSharding(mesh, P())),
-            donate_argnums=(0,),
-        )
-        return jitted, (shapes, specs)
 
     def inner(state: TrainState, batch, key, rid):
         # batch arrives as this rank's rows (split over dp by in_specs);
@@ -742,9 +659,8 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
                 plan, leaves_g, state.residuals, key,
                 data_axis=data_axis, p_data=p_data,
                 pod_axis=pod_axis, p_pod=p_pod,
-                native=native, data_rank=data_rank, pod_rank=pod_rank)
-            coll = comm.CollectiveContext(data_axis, p_data, native=native,
-                                          rank=data_rank)
+                data_rank=data_rank, pod_rank=pod_rank)
+            coll = comm.CollectiveContext(data_axis, p_data, rank=data_rank)
             new_p, new_opt, gnorm = _zero_scattered_update(
                 state.params, reduced, state.opt, lr, tcfg, plan, coll)
             new_state = TrainState(new_p, new_opt, new_res, state.step + 1)
@@ -753,13 +669,13 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
             plan, leaves_g, state.residuals, key,
             data_axis=data_axis, p_data=p_data,
             pod_axis=pod_axis, p_pod=p_pod,
-            native=native, data_rank=data_rank, pod_rank=pod_rank,
+            data_rank=data_rank, pod_rank=pod_rank,
         )
         synced = gtree.unflatten(synced_leaves)
         synced, gnorm = clip_by_global_norm(synced, tcfg.optimizer.grad_clip)
         if tcfg.zero1:
             gather_ctxs = [
-                comm.CollectiveContext(ax, mesh.shape[ax], native=native,
+                comm.CollectiveContext(ax, mesh.shape[ax],
                                        rank=(pod_rank if ax == pod_axis
                                              else data_rank))
                 for ax in dp_ax

@@ -318,13 +318,9 @@ def test_full_train_step_collective_count():
     from repro.optim.optimizers import OptimizerConfig
     from repro.optim.schedule import ScheduleConfig
     from repro.train.state import TrainConfig
-    from repro.train.train_step import (
-        build_train_step,
-        sparcml_uses_manual_collectives,
-    )
+    from repro.train.train_step import build_train_step
 
     mesh = make_mesh((8, 1), ("data", "model"))
-    assert sparcml_uses_manual_collectives(mesh)
     cfg = ModelConfig(name="ts", family="dense", num_layers=2, d_model=256,
                       num_heads=4, num_kv_heads=2, d_ff=512, vocab_size=512,
                       dtype=jnp.float32, param_dtype=jnp.float32,

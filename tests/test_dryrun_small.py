@@ -34,17 +34,12 @@ def test_train_cell_lowers_and_compiles(multi_pod, sync):
         lowered = step_fn.lower(shapes, b, key)
         compiled = lowered.compile()
         assert compiled.cost_analysis() is not None
-        # the paper's collectives must appear in sparcml mode (lowering
-        # depends on the backend path — DESIGN.md §4)
+        # the paper's collectives must appear in sparcml mode (native
+        # collectives in the manual-dp region — DESIGN.md §4)
         hlo = compiled.as_text()
         if sync == "sparcml":
-            from repro.train.train_step import sparcml_uses_manual_collectives
-            if sparcml_uses_manual_collectives(mesh):
-                assert "all-to-all" in hlo, "DSAR split phase missing"
-                assert "all-gather" in hlo, "DSAR gather phase missing"
-            else:
-                # auto-SPMD fallback: XLA inserts the dp reductions
-                assert "all-reduce" in hlo, "dp-axis reduction missing"
+            assert "all-to-all" in hlo, "DSAR split phase missing"
+            assert "all-gather" in hlo, "DSAR gather phase missing"
 
 
 @pytest.mark.parametrize("arch", ["mamba2-370m", "zamba2-2.7b", "dbrx-132b"])

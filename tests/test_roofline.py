@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import cost_analysis, shard_map
+from repro.compat import shard_map
 from repro.utils.hlo_cost import total_cost
 from repro.utils.roofline import Roofline, model_flops_train
 
@@ -19,7 +19,7 @@ def test_loop_free_flops_match_cost_analysis():
                    jax.ShapeDtypeStruct((512, 1024), jnp.float32),
                    jax.ShapeDtypeStruct((1024, 128), jnp.float32)).compile()
     mc = total_cost(comp.as_text())
-    np.testing.assert_allclose(mc.flops, cost_analysis(comp)["flops"], rtol=1e-6)
+    np.testing.assert_allclose(mc.flops, comp.cost_analysis()["flops"], rtol=1e-6)
 
 
 def test_scan_trip_count_multiplies():
@@ -35,7 +35,7 @@ def test_scan_trip_count_multiplies():
     np.testing.assert_allclose(mc.flops, 10 * 2 * 256 ** 3, rtol=1e-6)
     assert any(t == 10 for _, t in mc.trip_counts)
     # XLA's own analysis counts the body once — we must exceed it
-    assert mc.flops > cost_analysis(comp)["flops"] * 5
+    assert mc.flops > comp.cost_analysis()["flops"] * 5
 
 
 def test_collective_bytes_psum(mesh4x2):

@@ -104,7 +104,46 @@ def test_staleness0_matches_synchronous(mesh8x1, model, lowering):
         s_pipe, l_pipe = _run(pipe_fn, s_pipe, 3)
     np.testing.assert_allclose(l_sync, l_pipe, rtol=1e-5)
     assert s_pipe.inflight is None
-    _assert_state_close(s_sync, s_pipe)
+    # The lowerings compute each rank's gradient with different reduction
+    # orders, so an EF accumulator entry can differ by an ulp. Where two
+    # entries of one bucket tie in magnitude to that precision, top-k
+    # keeps a different one of the pair: the residuals then swap the
+    # two values, and the update differs at those two coordinates. Such
+    # flips are the only differences allowed; all else stays allclose.
+    flips = sum(_tie_flips(s_sync.residuals[n], s_pipe.residuals[n],
+                           SYNC.bucket_size) for n in s_sync.residuals)
+    for part, per_flip in (("params", 2), ("opt", 4)):   # opt: mu and nu
+        n_off = sum(_n_not_close(x, y) for x, y in zip(
+            jax.tree.leaves(getattr(s_sync, part)),
+            jax.tree.leaves(getattr(s_pipe, part))))
+        assert n_off <= per_flip * flips, (part, n_off, flips)
+
+
+def _n_not_close(a, b, rtol=2e-4, atol=1e-5) -> int:
+    return int(np.count_nonzero(~np.isclose(np.asarray(a), np.asarray(b),
+                                            rtol=rtol, atol=atol)))
+
+
+def _tie_flips(ra, rb, bucket_size, rtol=2e-4, atol=1e-5) -> int:
+    """Number of top-k selections that differ between two EF residual
+    buffers, asserting that each is a swap of a magnitude tie: in one
+    bucket, the entry one run kept (and the other sent, leaving 0) pairs
+    with an entry of the same magnitude, to a few ulps, the other way."""
+    a = np.asarray(ra).reshape(-1, bucket_size)
+    b = np.asarray(rb).reshape(-1, bucket_size)
+    off = ~np.isclose(a, b, rtol=rtol, atol=atol)
+    flips = 0
+    for r in np.flatnonzero(off.any(axis=1)):
+        cols = np.flatnonzero(off[r])
+        kept_a = cols[b[r, cols] == 0]      # b sent it, a kept it
+        kept_b = cols[a[r, cols] == 0]
+        assert len(kept_a) + len(kept_b) == len(cols), (r, a[r, cols],
+                                                        b[r, cols])
+        assert len(kept_a) == len(kept_b), (r, kept_a, kept_b)
+        np.testing.assert_allclose(np.sort(np.abs(a[r, kept_a])),
+                                   np.sort(np.abs(b[r, kept_b])), rtol=1e-6)
+        flips += len(kept_a)
+    return flips
 
 
 # --------------------------------------------------------------------------

@@ -33,7 +33,6 @@ from repro.train.state import TrainConfig
 from repro.train.train_step import (
     build_train_step,
     init_state,
-    sparcml_uses_manual_collectives,
     state_shapes,
 )
 
@@ -200,7 +199,6 @@ def test_scattered_manual_matches_replicated():
     allgather. Grad norm comes from a per-shard psum (different fp
     summation order), so parity is allclose, not bitwise."""
     mesh = make_mesh((8, 1), ("data", "model"))
-    assert sparcml_uses_manual_collectives(mesh)
     lr_, sr = _run_steps(mesh, _tcfg("replicated", "ssar_balanced_split"))
     ls_, ss = _run_steps(mesh, _tcfg("scattered", "ssar_balanced_split"))
     np.testing.assert_allclose(lr_, ls_, rtol=1e-4)
@@ -432,7 +430,6 @@ def test_pipelined_scattered_allgather_is_per_bucket():
     from repro.runtime.pipeline import build_pipelined_step
 
     mesh = make_mesh((8, 1), ("data", "model"))
-    assert sparcml_uses_manual_collectives(mesh)
     model = build_model(_model_cfg())
 
     def trace(mode):
