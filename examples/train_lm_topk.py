@@ -58,9 +58,8 @@ def main():
                          "model re-select collective algorithms at drain "
                          "barriers (with --pipeline)")
     ap.add_argument("--trace", type=str, default=None, metavar="PATH",
-                    help="export a Chrome-trace JSON of the run "
-                         "(host spans + derived device compute/comm "
-                         "phases, DESIGN.md §10)")
+                    help="export a Chrome-trace JSON of the run's "
+                         "host spans (DESIGN.md §10)")
     ap.add_argument("--metrics-out", type=str, default=None, metavar="PATH",
                     help="write the metrics/event JSONL (per-bucket "
                          "nnz/wire histograms, plan swaps, step times) "

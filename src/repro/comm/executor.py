@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.comm.buckets import pack_group, unpack_group
 from repro.comm.collectives import CollectiveContext
 from repro.comm.plan import SyncPlan
+from repro.obs import scopes
 
 # repro.core is imported lazily inside the functions below: core/__init__
 # re-exports core.compressor, which imports comm — see plan.py.
@@ -52,17 +53,18 @@ def _qsgd_rand(key, bucket_idx: int, coll: CollectiveContext,
     pod rank]). Emulated: every range's bits stacked (p, shard) — each
     rank replays every owner's rounding on the replicated psum result, so
     the emulated output is bit-identical to the native wire."""
-    sub = jax.random.fold_in(key, bucket_idx)
-    if pod_rank is not None:
-        sub = jax.random.fold_in(sub, pod_rank)
-    if coll.native:
-        sub = jax.random.fold_in(sub, coll.axis_rank())
-        return jax.random.bits(sub, (shard_elems,), dtype=jnp.uint32)
-    return jnp.stack([
-        jax.random.bits(jax.random.fold_in(sub, j), (shard_elems,),
-                        dtype=jnp.uint32)
-        for j in range(p)
-    ])
+    with scopes.scope(scopes.QSGD):
+        sub = jax.random.fold_in(key, bucket_idx)
+        if pod_rank is not None:
+            sub = jax.random.fold_in(sub, pod_rank)
+        if coll.native:
+            sub = jax.random.fold_in(sub, coll.axis_rank())
+            return jax.random.bits(sub, (shard_elems,), dtype=jnp.uint32)
+        return jnp.stack([
+            jax.random.bits(jax.random.fold_in(sub, j), (shard_elems,),
+                            dtype=jnp.uint32)
+            for j in range(p)
+        ])
 
 
 def _bucket_telemetry(out, plan, group, b, p_data: int, p_pod: int,
@@ -312,9 +314,10 @@ def reduce_buckets(
                 continue
 
             res = residuals[b.name][0]                        # strip replica axis
-            acc = res.astype(jnp.float32) + seg               # Alg. 2 line 1
-            u, residual = topk_mod.compress2d(
-                acc, cfg.k_per_bucket, cfg.bucket_size)       # Alg. 2 line 2
+            with scopes.scope(scopes.COMPRESS):
+                acc = res.astype(jnp.float32) + seg           # Alg. 2 line 1
+                u, residual = topk_mod.compress2d(
+                    acc, cfg.k_per_bucket, cfg.bucket_size)   # Alg. 2 line 2
 
             algorithm = b.algorithm
             # QSGD belongs to DSAR's dense gather phase ONLY: an SSAR
@@ -372,7 +375,8 @@ def reduce_buckets(
                 # contributed exactly once on a later step — no gradient
                 # mass is silently lost. Folded BEFORE the telemetry
                 # read so the reported EF norm covers the clamped mass.
-                residual = residual + fold[None, :]
+                with scopes.scope(scopes.COMPRESS):
+                    residual = residual + fold[None, :]
             if telemetry:
                 # Mass stats are per-rank sums here; ONE extra (3,) psum
                 # per EF bucket makes them global (in-graph collective —
@@ -454,16 +458,17 @@ def _qsgd_rand_all(key, bucket_idx: int, p_pod: int, p_data: int,
                    shard_elems: int):
     """(p_pod, p_data, shard) rounding bits — bit-compatible with the
     per-rank fold order of :func:`_qsgd_rand` (bucket, pod, data)."""
-    sub = jax.random.fold_in(key, bucket_idx)
-    pods = []
-    for a in range(p_pod):
-        sp = jax.random.fold_in(sub, a) if p_pod > 1 else sub
-        pods.append(jnp.stack([
-            jax.random.bits(jax.random.fold_in(sp, j), (shard_elems,),
-                            dtype=jnp.uint32)
-            for j in range(p_data)
-        ]))
-    return jnp.stack(pods)
+    with scopes.scope(scopes.QSGD):
+        sub = jax.random.fold_in(key, bucket_idx)
+        pods = []
+        for a in range(p_pod):
+            sp = jax.random.fold_in(sub, a) if p_pod > 1 else sub
+            pods.append(jnp.stack([
+                jax.random.bits(jax.random.fold_in(sp, j), (shard_elems,),
+                                dtype=jnp.uint32)
+                for j in range(p_data)
+            ]))
+        return jnp.stack(pods)
 
 
 def reduce_buckets_spmd(
@@ -553,9 +558,10 @@ def reduce_buckets_spmd(
                 bucket_idx += 1
                 continue
             res = residuals[b.name]                           # (R, rows, cols)
-            acc = res.astype(jnp.float32) + seg
-            u, residual = topk_mod.compress2d(
-                acc, cfg.k_per_bucket, cfg.bucket_size)
+            with scopes.scope(scopes.COMPRESS):
+                acc = res.astype(jnp.float32) + seg
+                u, residual = topk_mod.compress2d(
+                    acc, cfg.k_per_bucket, cfg.bucket_size)
             dens = u.densify()                                # (R, rows, m*B)
             rows, mb = dens.shape[1], dens.shape[2]
             dpod = dens.reshape(p_pod, p_data, rows, mb).sum(axis=1)

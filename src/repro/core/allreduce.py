@@ -51,6 +51,7 @@ from repro.core.cost_model import (
     select_algorithm,
 )
 from repro.kernels.bucket_scatter.ops import bucket_scatter
+from repro.obs import scopes
 
 
 @dataclass(frozen=True)
@@ -391,9 +392,10 @@ def _qsgd_roundtrip(x2d, rand2d, qsgd: QSGDConfig, impl: str, out_dtype):
     from repro.kernels.qsgd_pack.ops import qsgd_pack
     from repro.kernels.qsgd_unpack.ops import qsgd_unpack
 
-    packed, scale = qsgd_pack(x2d, rand2d, qsgd.bits, qsgd.scale_mode,
-                              impl=impl)
-    return qsgd_unpack(packed, scale, qsgd.bits, out_dtype, impl=impl)
+    with scopes.scope(scopes.QSGD):
+        packed, scale = qsgd_pack(x2d, rand2d, qsgd.bits, qsgd.scale_mode,
+                                  impl=impl)
+        return qsgd_unpack(packed, scale, qsgd.bits, out_dtype, impl=impl)
 
 
 def dsar_split_allgather_batched_inside(
@@ -504,22 +506,25 @@ def dsar_split_allgather_batched_inside(
 
     bq = qsgd.bucket_size
     nbq = shard_cols // bq
-    packed, scale = qsgd_pack(
-        shard.reshape(r * nbq, bq),
-        rand.reshape(-1)[: r * nbq * bq].reshape(r * nbq, bq), qsgd.bits,
-        qsgd.scale_mode, impl=impl)
-    w = packed.shape[-1]
-    # ONE gather: [packed u32 bitcast to f32 | scale f32] along axis 1
-    wire = jnp.concatenate(
-        [jax.lax.bitcast_convert_type(packed.reshape(r, nbq * w), jnp.float32),
-         scale.reshape(r, nbq)], axis=1)
+    with scopes.scope(scopes.QSGD):
+        packed, scale = qsgd_pack(
+            shard.reshape(r * nbq, bq),
+            rand.reshape(-1)[: r * nbq * bq].reshape(r * nbq, bq),
+            qsgd.bits, qsgd.scale_mode, impl=impl)
+        w = packed.shape[-1]
+        # ONE gather: [packed u32 bitcast to f32 | scale f32] along axis 1
+        wire = jnp.concatenate(
+            [jax.lax.bitcast_convert_type(packed.reshape(r, nbq * w),
+                                          jnp.float32),
+             scale.reshape(r, nbq)], axis=1)
     wire = coll.all_gather(wire, axis=1).reshape(r, p, nbq * w + nbq)
-    packed_all = jax.lax.bitcast_convert_type(
-        wire[..., : nbq * w], jnp.uint32)
-    scale_all = wire[..., nbq * w:]
-    xhat = qsgd_unpack(packed_all.reshape(r * p * nbq, w),
-                       scale_all.reshape(r * p * nbq, 1), qsgd.bits,
-                       jnp.float32, impl=impl)
+    with scopes.scope(scopes.QSGD):
+        packed_all = jax.lax.bitcast_convert_type(
+            wire[..., : nbq * w], jnp.uint32)
+        scale_all = wire[..., nbq * w:]
+        xhat = qsgd_unpack(packed_all.reshape(r * p * nbq, w),
+                           scale_all.reshape(r * p * nbq, 1), qsgd.bits,
+                           jnp.float32, impl=impl)
     # received order is (r, p, shard) — identical to the pre-fusion
     # two-gather layout, so the reshape back to (r, m*B) is unchanged
     return xhat.reshape(r, m * b).astype(out_dtype)

@@ -27,10 +27,8 @@ from __future__ import annotations
 
 from repro.obs.audit import (
     DriftAuditor,
-    attribute_step_phases,
     audit_serve_plan,
     audit_sync_plan,
-    time_phases,
 )
 from repro.obs.health import (
     HealthConfig,
@@ -168,7 +166,6 @@ __all__ = [
     "Observability",
     "OFF",
     "Tracer",
-    "attribute_step_phases",
     "audit_serve_plan",
     "audit_sync_plan",
     "configure",
@@ -177,6 +174,5 @@ __all__ = [
     "record_bucket_telemetry",
     "resolve",
     "set_default",
-    "time_phases",
     "validate_span_tree",
 ]

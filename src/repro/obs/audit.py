@@ -18,11 +18,6 @@ nobody checks those numbers against reality. This module closes the loop
   audit_serve_plan        same join for a ServePlan's activation
                           exchange (``exchange_activation_spmd`` vs the
                           stream/dense cost entries)
-  attribute_step_phases   lays the overlap model's compute / exposed-
-                          comm split into ONE measured step interval —
-                          the derived device-phase spans the tracer
-                          draws (solves the model for t_compute, then
-                          normalizes so the spans tile the measurement)
 
 Probes run the real executor halves but OUTSIDE the training loop (at
 drain barriers or run end), so the audit adds no sync points to the
@@ -249,83 +244,3 @@ def audit_serve_plan(plan, mesh, axis_name: str = "model", *, net=None,
     if registry is not None:
         auditor.emit(registry)
     return auditor
-
-
-# ---------------------------------------------------------------------------
-# Derived device-phase attribution.
-# ---------------------------------------------------------------------------
-
-def attribute_step_phases(dt_s: float, t_buckets, names=None,
-                          staleness: int = 1) -> list[dict]:
-    """Split one MEASURED step interval into compute + exposed per-bucket
-    comm phases consistent with the overlap model (DESIGN.md §6).
-
-    Solves ``t_c + sum(exposed_bucket_times(t_buckets, t_c)) == dt_s``
-    for the compute share ``t_c`` (the RHS is monotone in ``t_c``, so a
-    bisection converges); if the modeled full drain already exceeds the
-    measurement, the whole interval is attributed to comm, scaled to
-    fit. Returns phase dicts ``{name, cat, offset_s, dur_s, args}`` that
-    tile ``[0, dt_s]`` exactly — ready for ``Tracer.complete`` at
-    ``retire_end - dt_s``. These spans are DERIVED (model laid into a
-    measurement), which their ``cat`` says out loud; the honest
-    per-algorithm ground truth is the audit probes above."""
-    from repro.core.cost_model import exposed_bucket_times
-
-    t_buckets = [float(t) for t in t_buckets]
-    names = list(names) if names is not None else [
-        f"bucket{i}" for i in range(len(t_buckets))]
-    dt_s = float(dt_s)
-    if dt_s <= 0.0:
-        return []
-
-    if staleness == 0:
-        total = sum(t_buckets)
-        t_c = max(0.0, dt_s - total)
-        exposed = list(t_buckets)
-    else:
-        lo, hi = 0.0, dt_s
-        for _ in range(50):
-            mid = 0.5 * (lo + hi)
-            if mid + sum(exposed_bucket_times(t_buckets, mid)) < dt_s:
-                lo = mid
-            else:
-                hi = mid
-        t_c = 0.5 * (lo + hi)
-        exposed = exposed_bucket_times(t_buckets, t_c)
-
-    # Normalize so the phases tile the measured interval exactly.
-    total = t_c + sum(exposed)
-    scale = dt_s / total if total > 0 else 0.0
-    phases = []
-    off = 0.0
-    if t_c > 0:
-        dur = t_c * scale
-        phases.append({"name": "compute", "cat": "device.derived",
-                       "offset_s": off, "dur_s": dur,
-                       "args": {"modeled_s": t_c}})
-        off += dur
-    for name, exp, full in zip(names, exposed, t_buckets):
-        if exp <= 0:
-            continue
-        dur = exp * scale
-        phases.append({"name": f"comm/{name}", "cat": "device.derived",
-                       "offset_s": off, "dur_s": dur,
-                       "args": {"exposed_s": exp, "bucket_s": full,
-                                "hidden_s": full - exp}})
-        off += dur
-    return phases
-
-
-def time_phases(phases: dict) -> dict[str, float]:
-    """Time a dict of named thunks (the compose-able executor halves —
-    e.g. ``{"reduce": ..., "apply": ...}``), blocking each: the direct
-    measurement path for tests and offline audits. NOT for the pipelined
-    hot loop (it syncs per phase by construction)."""
-    import jax
-
-    out = {}
-    for name, fn in phases.items():
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        out[name] = time.perf_counter() - t0
-    return out

@@ -8,12 +8,15 @@ directly in ``chrome://tracing`` / Perfetto. Three event kinds:
                    context manager; spans opened on the same thread nest
                    by construction (enter/exit is LIFO per thread), so
                    the exported tree is always well-formed
-  complete(...)    an explicitly-timed complete event — how DERIVED
-                   device-phase spans (compute vs exposed comm, per
-                   bucket) are laid into a measured retire interval by
-                   the runtime (see obs/audit.attribute_step_phases)
   instant(name)    a zero-duration marker ("ph": "i") — plan swaps,
                    forced switches, checkpoint boundaries
+  counter(name)    a Chrome counter sample ("ph": "C")
+
+An enabled span is also a ``jax.profiler.TraceAnnotation`` for its
+duration, under its exact name with its args as TraceMe metadata: while
+a ``jax.profiler`` trace runs, every span lands on the profiler's host
+plane, on the same clock as the device's ops (DESIGN.md §10.1). With no
+profiler trace running the annotation records nothing.
 
 The tracer NEVER touches the device: no ``block_until_ready``, no array
 reads. Everything it records is host wall time, so tracing adds no sync
@@ -28,6 +31,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -46,9 +51,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One open host span; records a complete ("X") event on exit."""
+    """One open host span; records a complete ("X") event on exit and is
+    a profiler ``TraceAnnotation`` while open."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -57,11 +63,14 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self._name, **self._args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         tr._append({
             "name": self._name, "cat": self._cat, "ph": "X",
@@ -93,10 +102,6 @@ class Tracer:
         """Current trace-relative timestamp (microseconds)."""
         return (time.perf_counter() - self._born) * 1e6
 
-    def to_us(self, t_perf_counter: float) -> float:
-        """Map an absolute ``perf_counter`` reading onto the trace clock."""
-        return (t_perf_counter - self._born) * 1e6
-
     # -- recording ---------------------------------------------------------
     def _append(self, ev: dict) -> None:
         with self._lock:
@@ -107,18 +112,6 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
-
-    def complete(self, name: str, cat: str, /, ts_us: float, dur_us: float,
-                 tid: int | str = "derived", **args) -> None:
-        """Record an explicitly-timed complete event (derived spans)."""
-        if not self.enabled:
-            return
-        self._append({
-            "name": name, "cat": cat, "ph": "X",
-            "ts": float(ts_us), "dur": float(max(dur_us, 0.0)),
-            "pid": self.pid, "tid": tid,
-            **({"args": args} if args else {}),
-        })
 
     def instant(self, name: str, /, cat: str = "host", **args) -> None:
         if not self.enabled:

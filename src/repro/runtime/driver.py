@@ -28,13 +28,12 @@ watchdog compares against (``record_step``), what the
 read; the mean is only exact for whole-run aggregates.
 
 Observability (DESIGN.md §10): ``run_pipelined`` takes an ``obs`` handle
-(``repro.obs``). Host spans wrap dispatch/retire/drain/checkpoint, plan
-swaps and restarts become structured events, and — when tracing — a
-``phase_attr`` callback lays the cost model's compute/exposed-comm split
-into each retire interval as derived device-phase spans. All of it is
-host-side: with observability off the loop is byte-identical, and with
-it on, retire remains the only ``block_until_ready`` (tests/test_obs.py
-pins both properties).
+(``repro.obs``). Host spans wrap dispatch/retire/drain/checkpoint (and,
+under a ``jax.profiler`` trace, land beside the device's ops), plan
+swaps and restarts become structured events. All of it is host-side:
+with observability off the loop is byte-identical, and with it on,
+retire remains the only ``block_until_ready`` (tests/test_obs.py pins
+both properties).
 
 The driver is state-linear (step functions donate their input state), so
 after a dispatch only the returned state is live; on failure the window
@@ -240,7 +239,6 @@ def run_pipelined(
     restore_fn: Optional[Callable[[], Any]] = None,
     adapt=None,
     obs=None,
-    phase_attr: Optional[Callable[[float], list]] = None,
     health=None,
     recovery=None,
     injector=None,
@@ -263,9 +261,6 @@ def run_pipelined(
     obs: a ``repro.obs.Observability`` handle (None = session default,
     which defaults to OFF). Host spans + structured events only — the
     retire below stays the ONLY sync point either way.
-    phase_attr: ``dt_unit_s -> [phase dict]`` (see
-    ``obs.attribute_step_phases``); when tracing, each retire interval
-    is tiled with the derived compute/exposed-comm device spans.
     health: a ``repro.obs.health.HealthMonitor`` — evaluated at drain
     barriers and at end of run (host-side registry reads, no sync);
     verdicts land as ``health/*`` events, and when ``adapt`` exposes an
@@ -332,7 +327,6 @@ def run_pipelined(
         now = time.perf_counter()
         dt_unit = now - last_retire_t
         dt = dt_unit / k
-        prev_t = last_retire_t
         last_retire_t = now
         losses = np.atleast_1d(np.asarray(metrics["loss"]))
         n_stragglers = len(log.straggler_events)
@@ -372,15 +366,6 @@ def run_pipelined(
                      loss=float(losses[-1] if k > 1 else losses[0]))
             if len(log.straggler_events) > n_stragglers:
                 rec._safe_dump("watchdog")
-        if obs.trace_on and phase_attr is not None:
-            # Lay the derived device phases into the measured interval
-            # [prev retire, this retire] on their own trace track.
-            for ph in phase_attr(dt_unit):
-                obs.tracer.complete(
-                    ph["name"], ph["cat"],
-                    ts_us=obs.tracer.to_us(prev_t + ph["offset_s"]),
-                    dur_us=ph["dur_s"] * 1e6, tid="device-phases",
-                    **ph.get("args", {}))
         if adapt is not None:
             adapt.observe(s0, k, metrics)
 

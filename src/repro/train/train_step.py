@@ -34,6 +34,7 @@ from repro.core import compressor as comp
 from repro.models.config import ModelConfig
 from repro.models.model import Model
 from repro.models.specs import param_specs
+from repro.obs import scopes
 from repro.optim.optimizers import clip_by_global_norm, init_opt_state, opt_update
 from repro.optim.schedule import make_schedule
 from repro.train.state import TrainConfig, TrainState
@@ -610,11 +611,14 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
 
         def step_fn(state: TrainState, batch, key):
             lr = sched(state.step)
-            loss, grads = _accumulated_grads(model, state.params, batch, n_micro,
-                                             mesh=mesh)
-            grads, gnorm = clip_by_global_norm(grads, tcfg.optimizer.grad_clip)
-            new_p, new_opt = opt_update(
-                state.params, grads, state.opt, lr, tcfg.optimizer)
+            with scopes.scope(scopes.MODEL):
+                loss, grads = _accumulated_grads(
+                    model, state.params, batch, n_micro, mesh=mesh)
+            with scopes.scope(scopes.UPDATE):
+                grads, gnorm = clip_by_global_norm(
+                    grads, tcfg.optimizer.grad_clip)
+                new_p, new_opt = opt_update(
+                    state.params, grads, state.opt, lr, tcfg.optimizer)
             new_state = TrainState(new_p, new_opt, None, state.step + 1)
             return new_state, {"loss": loss, "grad_norm": gnorm, "lr": lr}
 
@@ -642,7 +646,9 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
         # arange(dp_total)) — jax.lax.axis_index does not lower in
         # partial-manual regions on the emulated backends.
         lr = sched(state.step)
-        loss, grads = _accumulated_grads(model, state.params, batch, n_micro)
+        with scopes.scope(scopes.MODEL):
+            loss, grads = _accumulated_grads(model, state.params, batch,
+                                             n_micro)
         loss = jax.lax.pmean(loss, dp_ax[-1])
         if len(dp_ax) > 1:
             loss = jax.lax.pmean(loss, dp_ax[0])
@@ -655,37 +661,42 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh: Mesh):
             # shard, the update runs there, and the only gather left is
             # the dense param allgather inside the update (one per
             # bucket). Grad norm comes back exactly from the shards.
-            reduced, new_res, _ = comm.reduce_buckets(
+            with scopes.scope(scopes.SYNC):
+                reduced, new_res, _ = comm.reduce_buckets(
+                    plan, leaves_g, state.residuals, key,
+                    data_axis=data_axis, p_data=p_data,
+                    pod_axis=pod_axis, p_pod=p_pod,
+                    data_rank=data_rank, pod_rank=pod_rank)
+            coll = comm.CollectiveContext(data_axis, p_data, rank=data_rank)
+            with scopes.scope(scopes.UPDATE):
+                new_p, new_opt, gnorm = _zero_scattered_update(
+                    state.params, reduced, state.opt, lr, tcfg, plan, coll)
+            new_state = TrainState(new_p, new_opt, new_res, state.step + 1)
+            return new_state, {"loss": loss, "grad_norm": gnorm, "lr": lr}
+        with scopes.scope(scopes.SYNC):
+            synced_leaves, new_res = comm.execute_plan(
                 plan, leaves_g, state.residuals, key,
                 data_axis=data_axis, p_data=p_data,
                 pod_axis=pod_axis, p_pod=p_pod,
-                data_rank=data_rank, pod_rank=pod_rank)
-            coll = comm.CollectiveContext(data_axis, p_data, rank=data_rank)
-            new_p, new_opt, gnorm = _zero_scattered_update(
-                state.params, reduced, state.opt, lr, tcfg, plan, coll)
-            new_state = TrainState(new_p, new_opt, new_res, state.step + 1)
-            return new_state, {"loss": loss, "grad_norm": gnorm, "lr": lr}
-        synced_leaves, new_res = comm.execute_plan(
-            plan, leaves_g, state.residuals, key,
-            data_axis=data_axis, p_data=p_data,
-            pod_axis=pod_axis, p_pod=p_pod,
-            data_rank=data_rank, pod_rank=pod_rank,
-        )
+                data_rank=data_rank, pod_rank=pod_rank,
+            )
         synced = gtree.unflatten(synced_leaves)
-        synced, gnorm = clip_by_global_norm(synced, tcfg.optimizer.grad_clip)
-        if tcfg.zero1:
-            gather_ctxs = [
-                comm.CollectiveContext(ax, mesh.shape[ax],
-                                       rank=(pod_rank if ax == pod_axis
-                                             else data_rank))
-                for ax in dp_ax
-            ]
-            new_p, new_opt = _zero1_update(
-                state.params, synced, state.opt, lr, tcfg, pspecs,
-                dp_ax, dp_index, dp_total, gather_ctxs)
-        else:
-            new_p, new_opt = opt_update(
-                state.params, synced, state.opt, lr, tcfg.optimizer)
+        with scopes.scope(scopes.UPDATE):
+            synced, gnorm = clip_by_global_norm(synced,
+                                                tcfg.optimizer.grad_clip)
+            if tcfg.zero1:
+                gather_ctxs = [
+                    comm.CollectiveContext(ax, mesh.shape[ax],
+                                           rank=(pod_rank if ax == pod_axis
+                                                 else data_rank))
+                    for ax in dp_ax
+                ]
+                new_p, new_opt = _zero1_update(
+                    state.params, synced, state.opt, lr, tcfg, pspecs,
+                    dp_ax, dp_index, dp_total, gather_ctxs)
+            else:
+                new_p, new_opt = opt_update(
+                    state.params, synced, state.opt, lr, tcfg.optimizer)
         new_state = TrainState(new_p, new_opt, new_res, state.step + 1)
         return new_state, {"loss": loss, "grad_norm": gnorm, "lr": lr}
 

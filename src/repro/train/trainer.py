@@ -145,48 +145,69 @@ class Trainer:
 
     # -- main loop ---------------------------------------------------------
     def run(self, num_steps: int, fail_at: Optional[int] = None) -> TrainerLog:
-        """Train for num_steps (absolute). fail_at injects a fault for tests."""
-        if self.state is None:
-            self.init_or_resume()
-        if self.state.inflight is not None:
-            # hand-off from a pipelined run: drop the in-flight reduction
-            # (one step of gradients — the documented lossy-accumulator
-            # deal, same as the EF reset on elastic restarts)
-            self.state = self.state._replace(inflight=None)
-        with self.mesh:
-            while int(self.state.step) < num_steps:
-                step = int(self.state.step)
-                batch = jax.tree.map(
-                    jax.numpy.asarray, synthetic_batch(self.data_cfg, step))
-                key = jax.random.fold_in(self._root_key, step)
-                t0 = time.perf_counter()
-                try:
-                    if fail_at is not None and step == fail_at:
-                        fail_at = None  # fail exactly once
-                        raise RuntimeError("injected node failure")
-                    new_state, metrics = self.step_fn(self.state, batch, key)
-                    jax.block_until_ready(metrics["loss"])
-                except Exception:
-                    # node-failure path: restore + replay
-                    if not self.ckpt_dir:
-                        raise
-                    self.log.restarts += 1
-                    self.state = ckpt.restore(
-                        self.ckpt_dir, self._abstract_like(),
-                        dp_total=dp_total_of(self.mesh))
-                    continue
-                dt = time.perf_counter() - t0
-                self.state = new_state
-                record_step(self.log, step, dt, float(metrics["loss"]),
-                            self.straggler_factor)
-                if self.ckpt_dir and (step + 1) % self.ckpt_every == 0:
-                    ckpt.save(self.ckpt_dir, self.state,
-                              dp_total=dp_total_of(self.mesh),
-                              opt_layout=ckpt.opt_layout_of(self.tcfg))
-        if self.ckpt_dir:
-            ckpt.save(self.ckpt_dir, self.state, dp_total=dp_total_of(self.mesh),
-                      opt_layout=ckpt.opt_layout_of(self.tcfg))
+        """Train for num_steps (absolute). fail_at injects a fault for tests.
+
+        Traced (``obs`` with tracing on), the call is one ``train/run``
+        span; each step's ``train/batch`` (rows, their transfer, the step
+        key), ``train/dispatch`` (the jitted call), ``train/wait`` (until
+        the loss is ready), ``train/log`` (the loss read and
+        ``record_step``) and ``train/checkpoint`` nest in it. What
+        ``train/run`` holds beyond them is the loop's own bookkeeping,
+        the reads of ``state.step`` among it (DESIGN.md §10.1)."""
+        span = self.obs.span
+        with span("train/run", num_steps=num_steps):
+            if self.state is None:
+                self.init_or_resume()
+            if self.state.inflight is not None:
+                # hand-off from a pipelined run: drop the in-flight
+                # reduction (one step of gradients — the documented
+                # lossy-accumulator deal, same as the EF reset on
+                # elastic restarts)
+                self.state = self.state._replace(inflight=None)
+            with self.mesh:
+                while int(self.state.step) < num_steps:
+                    step = int(self.state.step)
+                    with span("train/batch", step=step):
+                        batch = jax.tree.map(
+                            jax.numpy.asarray,
+                            synthetic_batch(self.data_cfg, step))
+                        key = jax.random.fold_in(self._root_key, step)
+                    t0 = time.perf_counter()
+                    try:
+                        if fail_at is not None and step == fail_at:
+                            fail_at = None  # fail exactly once
+                            raise RuntimeError("injected node failure")
+                        with span("train/dispatch", step=step):
+                            new_state, metrics = self.step_fn(
+                                self.state, batch, key)
+                        with span("train/wait", step=step):
+                            jax.block_until_ready(metrics["loss"])
+                    except Exception:
+                        # node-failure path: restore + replay
+                        if not self.ckpt_dir:
+                            raise
+                        self.log.restarts += 1
+                        self.state = ckpt.restore(
+                            self.ckpt_dir, self._abstract_like(),
+                            dp_total=dp_total_of(self.mesh))
+                        continue
+                    dt = time.perf_counter() - t0
+                    self.state = new_state
+                    with span("train/log", step=step):
+                        record_step(self.log, step, dt,
+                                    float(metrics["loss"]),
+                                    self.straggler_factor)
+                    if self.ckpt_dir and (step + 1) % self.ckpt_every == 0:
+                        self._save()
+            if self.ckpt_dir:
+                self._save()
         return self.log
+
+    def _save(self) -> None:
+        with self.obs.span("train/checkpoint"):
+            ckpt.save(self.ckpt_dir, self.state,
+                      dp_total=dp_total_of(self.mesh),
+                      opt_layout=ckpt.opt_layout_of(self.tcfg))
 
     # -- non-blocking runtime (DESIGN.md §6/§7) ----------------------------
     def run_pipelined(self, num_steps: int, *, staleness: int = 1,
@@ -311,30 +332,6 @@ class Trainer:
                                                        self.mesh)
             return restored
 
-        phase_attr = None
-        if self.obs.trace_on:
-            # Derived device-phase attribution (DESIGN.md §10): lay the
-            # cost model's compute / exposed-comm split of the ACTIVE
-            # plan into each retire interval. Host arithmetic only.
-            from repro.core.cost_model import DEFAULT_NET, plan_bucket_times
-            from repro.obs import attribute_step_phases
-
-            attr_net = getattr(self, "_net_cal", None) or DEFAULT_NET
-
-            def phase_attr(dt_unit: float) -> list:
-                active = getattr(runtime, "current_plan", None) or plan
-                tb = plan_bucket_times(active, net=attr_net)
-                names = [b.name for b in active.buckets]
-                k = max(1, superstep)
-                per = attribute_step_phases(dt_unit / k, tb, names=names,
-                                            staleness=staleness)
-                out = []
-                for i in range(k):
-                    base = i * dt_unit / k
-                    out.extend({**ph, "offset_s": base + ph["offset_s"]}
-                               for ph in per)
-                return out
-
         health = None
         if self.obs.metrics_on:
             # compression-health rules over the run's registry: EF-norm
@@ -360,8 +357,7 @@ class Trainer:
                 ckpt_fn=ckpt_fn if self.ckpt_dir else None,
                 restore_fn=restore_fn if self.ckpt_dir else None,
                 adapt=runtime,
-                obs=self.obs, phase_attr=phase_attr,
-                health=health,
+                obs=self.obs, health=health,
                 recovery=recovery, injector=injector,
             )
         self.state = state

@@ -8,8 +8,8 @@ The two load-bearing invariants:
   shared no-op context manager;
 * obs ON adds NO sync points: retire's ``block_until_ready`` stays the
   only one (counted under a monkeypatch), the span tree is well-formed
-  Chrome-trace JSON, and the derived device phases tile each retire
-  interval exactly.
+  Chrome-trace JSON, and every span also lands under its own name on the
+  ``jax.profiler`` trace's host plane.
 """
 import json
 import os
@@ -33,7 +33,6 @@ from repro.obs import (
     NULL_TRACER,
     Observability,
     Tracer,
-    attribute_step_phases,
     audit_sync_plan,
     record_bucket_telemetry,
     validate_span_tree,
@@ -73,7 +72,6 @@ def test_null_tracer_is_shared_noop():
     # call (no allocation), and recording is a no-op
     assert NULL_TRACER.span("a") is NULL_TRACER.span("b") is _NULL_SPAN
     NULL_TRACER.instant("x")
-    NULL_TRACER.complete("x", "c", 0.0, 1.0)
     assert NULL_TRACER.events == []
 
 
@@ -279,29 +277,6 @@ def test_drift_auditor_flags_drifted_algorithm():
     assert "bad_alg" in aud.summary() and "DRIFT" in aud.summary()
 
 
-def test_attribute_step_phases_tile_interval():
-    dt = 0.010
-    for staleness in (0, 1):
-        phases = attribute_step_phases(dt, [0.002, 0.001],
-                                       names=["b0", "b1"],
-                                       staleness=staleness)
-        assert phases[0]["name"] == "compute"
-        # phases tile [0, dt] exactly: contiguous offsets, total == dt
-        off = 0.0
-        for ph in phases:
-            assert ph["offset_s"] == pytest.approx(off, abs=1e-12)
-            off += ph["dur_s"]
-        assert off == pytest.approx(dt, rel=1e-9)
-    # staleness=0 (sequential): exposed comm == full bucket times
-    ph0 = attribute_step_phases(dt, [0.002, 0.001], staleness=0)
-    comm = [p for p in ph0 if p["name"].startswith("comm/")]
-    assert sum(p["dur_s"] for p in comm) == pytest.approx(0.003)
-    # an interval smaller than the modeled drain still tiles (all comm)
-    tiny = attribute_step_phases(0.001, [0.002, 0.001], staleness=0)
-    assert sum(p["dur_s"] for p in tiny) == pytest.approx(0.001)
-    assert attribute_step_phases(0.0, [0.001]) == []
-
-
 # --------------------------------------------------------------------------
 # Driver integration: no extra syncs, well-formed trace, bounded overhead
 # --------------------------------------------------------------------------
@@ -320,7 +295,7 @@ def pipelined(mesh8x1):
     return model, fn, plan
 
 
-def _drive(mesh, model, fn, plan, n=8, obs=None, phase_attr=None):
+def _drive(mesh, model, fn, plan, n=8, obs=None):
     with mesh:
         state, _ = init_state(model, TCFG, mesh)
         state = rt_pipeline.attach_inflight(state, plan, mesh)
@@ -330,14 +305,13 @@ def _drive(mesh, model, fn, plan, n=8, obs=None, phase_attr=None):
             key_fn=lambda s: jax.random.fold_in(KEY, s),
             cfg=rt_driver.DriverConfig(depth=2, prefetch=2,
                                        steps_per_unit=2),
-            obs=obs, phase_attr=phase_attr)
+            obs=obs)
     return state, log
 
 
 def test_driver_obs_adds_no_sync_points(mesh8x1, pipelined, monkeypatch):
     """Retire's ``block_until_ready`` is the ONLY sync point — the same
-    count with observability off and fully on (trace+metrics+derived
-    phases)."""
+    count with observability off and fully on (trace+metrics)."""
     model, fn, plan = pipelined
     real = jax.block_until_ready
     counts = {"n": 0}
@@ -346,21 +320,18 @@ def test_driver_obs_adds_no_sync_points(mesh8x1, pipelined, monkeypatch):
         counts["n"] += 1
         return real(x)
 
-    def run(obs, phase_attr=None):
+    def run(obs):
         counts["n"] = 0
         monkeypatch.setattr(jax, "block_until_ready", counting)
         try:
-            _drive(mesh8x1, model, fn, plan, n=8, obs=obs,
-                   phase_attr=phase_attr)
+            _drive(mesh8x1, model, fn, plan, n=8, obs=obs)
         finally:
             monkeypatch.setattr(jax, "block_until_ready", real)
         return counts["n"]
 
     off = run(obs_mod.Observability())            # all-off handle
     on = run(obs_mod.configure(trace=True, metrics=True,
-                               set_as_default=False),
-             phase_attr=lambda dt: attribute_step_phases(
-                 dt, [dt * 0.05, dt * 0.03], names=["b0", "b1"]))
+                               set_as_default=False))
     assert off == on == 4        # one retire per 2-step unit, 8 steps
 
 
@@ -368,14 +339,8 @@ def test_driver_trace_well_formed_and_metrics_backed(mesh8x1, pipelined,
                                                      tmp_path):
     model, fn, plan = pipelined
     obs = obs_mod.configure(trace=True, metrics=True, set_as_default=False)
-    # staleness=0 (sequential model): comm is always exposed, so every
-    # retire interval gets compute + both bucket phases (under the
-    # staleness=1 model, buckets this small hide entirely under compute)
-    phase_attr = lambda dt: attribute_step_phases(   # noqa: E731
-        dt, [dt * 0.05, dt * 0.03], names=["b0", "b1"], staleness=0)
     n = 8
-    state, log = _drive(mesh8x1, model, fn, plan, n=n, obs=obs,
-                        phase_attr=phase_attr)
+    state, log = _drive(mesh8x1, model, fn, plan, n=n, obs=obs)
     assert int(state.step) == n
 
     # the DriverLog's public lists ARE registry series views
@@ -383,17 +348,10 @@ def test_driver_trace_well_formed_and_metrics_backed(mesh8x1, pipelined,
     assert len(log.losses) == n == len(log.step_times)
     assert obs.metrics.histogram("driver/retire_wall_s").snapshot()["count"] == 4
 
-    # well-formed span tree with the driver's host spans present...
+    # well-formed span tree with the driver's host spans present
     assert validate_span_tree(obs.tracer.events) == []
     names = {e["name"] for e in obs.tracer.events if e["ph"] == "X"}
     assert {"driver/dispatch", "driver/retire"} <= names
-    # ...and the derived device phases on their own track, tiling each
-    # retire interval (compute + both buckets per unit)
-    derived = [e for e in obs.tracer.events
-               if e.get("tid") == "device-phases"]
-    assert {e["name"] for e in derived} == {"compute", "comm/b0", "comm/b1"}
-    assert len(derived) == 3 * 4
-    assert all(e["cat"] == "device.derived" for e in derived)
 
     # the export is loadable Chrome-trace JSON
     doc = json.load(open(obs.tracer.export(str(tmp_path / "t.json"))))
@@ -401,30 +359,154 @@ def test_driver_trace_well_formed_and_metrics_backed(mesh8x1, pipelined,
 
 
 def test_driver_obs_overhead_bounded(mesh8x1, pipelined):
-    """Tracing budget: <=5% per-step overhead target at 8 emulated
-    devices. Measured as best-of-2 ABBA-paired run totals; the assert
-    allows extra headroom for shared-runner noise, and still catches any
-    accidental per-span sync or allocation storm."""
+    """Tracing budget: what an enabled tracer adds to a driven step stays
+    under 5% of the step. Priced as the events a traced, metrics-on
+    driver run records per step times the cost of one enabled span (best
+    of 5 tight loops, into a tracer already holding a million events, as
+    a long run's does), against that run's median step time. A span that
+    allocated in proportion to the trace, or a driver that recorded
+    events per bucket or per array, blows the budget; the load of a
+    shared machine moves both sides alike, since no two whole-run wall
+    times are compared.
+    (Sync points are pinned by test_driver_obs_adds_no_sync_points.)"""
     model, fn, plan = pipelined
-    phase_attr = lambda dt: attribute_step_phases(   # noqa: E731
-        dt, [dt * 0.05, dt * 0.03], names=["b0", "b1"])
+    obs = obs_mod.configure(trace=True, metrics=True, set_as_default=False)
+    n = 8
+    _, log = _drive(mesh8x1, model, fn, plan, n=n, obs=obs)
+    events_per_step = len(obs.tracer.events) / n
+    step_s = float(np.median(log.step_times))
 
-    def timed(obs, pa):
+    tr = Tracer()
+    tr.events = [{}] * 1_000_000
+
+    def span_s(reps=5000):
         t0 = time.perf_counter()
-        _drive(mesh8x1, model, fn, plan, n=8, obs=obs, phase_attr=pa)
-        return time.perf_counter() - t0
+        for i in range(reps):
+            with tr.span("driver/dispatch", step=i, k=2):
+                pass
+        return (time.perf_counter() - t0) / reps
 
-    def on():
-        return timed(obs_mod.configure(trace=True, metrics=True,
-                                       set_as_default=False), phase_attr)
+    per_span = min(span_s() for _ in range(5))
+    assert events_per_step * per_span <= 0.05 * step_s, (
+        events_per_step, per_span, step_s)
 
-    def off():
-        return timed(obs_mod.Observability(), None)
 
-    t_off = min(off(), off())
-    t_on = min(on(), on())
-    t_off = min(t_off, off())   # ABBA(A): bracket drift both ways
-    assert t_on <= 1.15 * t_off, (t_on, t_off)
+# --------------------------------------------------------------------------
+# Program spans on the profiler's clock; the train step's named scopes
+# --------------------------------------------------------------------------
+
+def test_enabled_span_lands_on_profiler_host_plane(tmp_path):
+    """An enabled span is a TraceAnnotation under its exact name, its
+    args as metadata; the OFF handle's span writes nothing."""
+    import glob
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_mod.configure(trace=True, set_as_default=False).span(
+                "obs/probe", step=7):
+            jnp.ones(4).block_until_ready()
+        with obs_mod.OFF.span("obs/off_probe"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = [e for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+    probes = [e for e in host if e.name == "obs/probe"]
+    assert len(probes) == 1 and probes[0].duration_ns > 0
+    assert ("step", 7) in list(probes[0].stats)
+    assert not [e for e in host if "off_probe" in e.name]
+
+
+@pytest.fixture(scope="module")
+def traced_trainer(tmp_path_factory):
+    """A tiny sparcml Trainer (top-k, 4-bit QSGD, ZeRO-1, 2 microbatches)
+    run 4 steps with tracing on and a checkpoint every 2 steps."""
+    import dataclasses
+
+    from repro.train.trainer import Trainer
+
+    mesh = make_mesh((8, 1), ("data", "model"))
+    tcfg = dataclasses.replace(
+        TCFG, microbatches=2,
+        sync=dataclasses.replace(SYNC, qsgd_bits=4, qsgd_bucket=128))
+    ob = obs_mod.configure(trace=True, set_as_default=False)
+    tr = Trainer(build_model(MODEL_CFG), tcfg, mesh,
+                 DataConfig(global_batch=16, seq_len=32, vocab_size=256),
+                 ckpt_dir=str(tmp_path_factory.mktemp("ckpt")),
+                 ckpt_every=2, obs=ob)
+    tr.run(4)
+    return tr
+
+
+def test_trainer_run_emits_train_spans(traced_trainer):
+    events = traced_trainer.obs.tracer.events
+    assert validate_span_tree(events) == []
+    spans = [e for e in events if e["ph"] == "X"]
+    count = {n: sum(e["name"] == n for e in spans)
+             for n in {e["name"] for e in spans}}
+    # one run call; 4 steps of batch/dispatch/wait/log; checkpoints after
+    # steps 2 and 4 and the closing one
+    assert count == {"train/run": 1, "train/batch": 4, "train/dispatch": 4,
+                     "train/wait": 4, "train/log": 4, "train/checkpoint": 3}
+    run, = (e for e in spans if e["name"] == "train/run")
+    assert all(run["ts"] <= e["ts"] and
+               e["ts"] + e["dur"] <= run["ts"] + run["dur"] for e in spans)
+
+
+def test_op_scopes_cover_step_parts(traced_trainer):
+    from repro.obs import scopes
+
+    tr = traced_trainer
+    batch = jax.tree.map(jnp.asarray, synthetic_batch(tr.data_cfg, 0))
+    with tr.mesh:
+        text = tr.step_fn.lower(tr.state, batch, KEY).compile().as_text()
+    found = set(scopes.op_scopes(text).values())
+    assert {scopes.MODEL, scopes.COMPRESS, scopes.QSGD,
+            scopes.UPDATE} <= found <= set(scopes.SCOPES)
+
+
+def test_scope_of_names_innermost_scope():
+    from repro.obs.scopes import op_scopes, scope, scope_of
+
+    assert scope_of("jit(stepped)/shard_map/sync/compress/top_k") == \
+        "sync/compress"
+    assert scope_of("jit(stepped)/shard_map/sync/all_to_all") == "sync"
+    # the backward pass wraps the scope in transforms
+    assert scope_of("jit(f)/model/while/body/transpose(jvp(model))/mul") \
+        == "model"
+    assert scope_of("jit(f)/update/sync") == "update"
+    assert scope_of("jit(f)/shard_map/add") is None
+    # the primitive (last component) never names a scope
+    assert scope_of("jit(f)/update") is None
+    text = """\
+%body.1 (p.1: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %p.1 = (s32[], f32[8]{0}) parameter(0)
+  %dot.5 = f32[8]{0} dot(f32[8]{0} %p.1, f32[8]{0} %p.1)
+  ROOT %tuple.2 = (s32[], f32[8]{0}) tuple(%p.1, %dot.5)
+}
+
+ENTRY %main.9 (p.0: f32[8]) -> f32[64] {
+  %p.0 = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %while.3 = (s32[], f32[8]{0}) while(%p.0), condition=%cond.1, \
+body=%body.1, metadata={op_name="jit(s)/model/while"}
+  %fusion.3 = f32[8]{0} fusion(f32[8]{0} %p.0), kind=kLoop, \
+metadata={op_name="jit(s)/update/sub" source_line=3}
+  %copy.9 = f32[8]{0} copy(%fusion.3)
+  %copy.10 = f32[8]{0} copy(%p.0)
+  ROOT %sort.4 = f32[64]{0} sort(%copy.9), dimensions={0}, \
+metadata={op_name="jit(s)/sync/compress/jit(sort)/sort"}
+}
+"""
+    # the loop body's ops take the loop's scope, the inserted copy its
+    # operand's; the parameter and its copy have none
+    assert op_scopes(text) == {
+        "p.1": "model", "dot.5": "model", "tuple.2": "model",
+        "while.3": "model", "fusion.3": "update", "copy.9": "update",
+        "sort.4": "sync/compress"}
+    with pytest.raises(ValueError):
+        scope("sync/topk")
 
 
 def test_record_step_straggler_watchdog():
