@@ -8,6 +8,7 @@ all_to_all split phase of the allreduce needs no dynamic message sizes.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -109,25 +110,81 @@ class BatchedStream(NamedTuple):
         return dense.reshape(*lead, m * b)
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
+def _rounds(xb: jax.Array, k: int):
+    """Sort-free selection over the last axis of ``xb``.
+
+    The buckets' entries are ordered by (|x| descending, index ascending)
+    -- ``lax.top_k``'s order, so the selected set is the same -- and round
+    r takes the first entry after round r-1's pick with one variadic
+    reduction that carries (key, index, x). Keys are |x|'s f32 bits as
+    int32: the floats' order, nan above inf, no flush of subnormals. The
+    rounds are a loop, so the chip holds one round's code, not k. An
+    entry is selected when it is not after the last pick. The k pairs are
+    put in ascending index order by rank (the count of smaller selected
+    indices). Jitted, so an eager caller dispatches one program, not the
+    ops one by one. Returns (lidx, val, selection mask)."""
+    # buckets first: the rows, not the few buckets of a row, tile the
+    # second-minor axis of the arrays the loop carries
+    xb = jnp.moveaxis(xb, -2, 0)
+    ax = xb.ndim - 1
+    lead = xb.shape[:-1]
+    key = jax.lax.bitcast_convert_type(jnp.abs(xb).astype(jnp.float32),
+                                       jnp.int32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, xb.shape, ax)
+
+    def first(a, b):
+        take = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jax.lax.select(take, p, q) for p, q in zip(a, b))
+
+    def after(kv, j):
+        kv, j = kv[..., None], j[..., None]
+        return (key < kv) | ((key == kv) & (iota > j))
+
+    init = (jnp.int32(-1), jnp.int32(xb.shape[-1]), jnp.zeros((), xb.dtype))
+
+    def round_(r, carry):
+        kv, j, lidx, val = carry
+        live = jnp.where(after(kv, j), key, -1)
+        kv, j, v = jax.lax.reduce((live, iota, xb), init, first, (ax,))
+        return (kv, j, jax.lax.dynamic_update_index_in_dim(lidx, j, r, ax),
+                jax.lax.dynamic_update_index_in_dim(val, v, r, ax))
+
+    # everything is after (int32 max, -1): |x|'s bits are at most that
+    carry = (jnp.full(lead, jnp.iinfo(jnp.int32).max, jnp.int32),
+             jnp.full(lead, -1, jnp.int32),
+             jnp.zeros((*lead, k), jnp.int32), jnp.zeros((*lead, k), xb.dtype))
+    kv, j, lidx, val = jax.lax.fori_loop(0, k, round_, carry)
+    bits = jax.lax.bitcast_convert_type(val, jnp.dtype(
+        f"int{8 * jnp.dtype(xb.dtype).itemsize}"))
+    rank = jnp.sum(lidx[..., None, :] < lidx[..., :, None], axis=-1)
+    slot = rank[..., :, None] == jnp.arange(k, dtype=rank.dtype)  # (.., i, p)
+    # slot p takes the one pair of rank p: an integer sum over one live
+    # entry moves an index, or a value's bits, unchanged
+    lidx = jnp.sum(jnp.where(slot, lidx[..., :, None], 0), axis=-2)
+    bits = jnp.sum(jnp.where(slot, bits[..., :, None], 0), axis=-2,
+                   dtype=bits.dtype)
+    return (jnp.moveaxis(lidx, 0, -2),
+            jnp.moveaxis(jax.lax.bitcast_convert_type(bits, xb.dtype), 0, -2),
+            jnp.moveaxis(~after(kv, j), 0, -2))
+
+
 def compress2d(
     x: jax.Array, k_per_bucket: int, bucket_size: int = 512
 ) -> tuple[BatchedStream, jax.Array]:
     """Batched TopK compression of a canonical (*lead, cols) layout.
 
-    Pure batched-jnp (top_k/sort/take_along_axis operate on the last
-    axis only — the leading dims are never merged or split), so every
-    leading axis keeps whatever sharding it has. Returns
-    (stream, residual (*lead, cols))."""
+    The selection is sort-free (:func:`_rounds`): k rounds of one
+    reduction per bucket, the same set as ``lax.top_k``. It operates on the
+    last axis only -- the leading dims are never merged or split -- so
+    every leading axis keeps whatever sharding it has. Returns (stream,
+    residual (*lead, cols))."""
     *lead, cols = x.shape
     b = bucket_size
     assert cols % b == 0, (x.shape, b)
+    assert 0 < k_per_bucket <= b, (k_per_bucket, b)
     m = cols // b
     xb = x.reshape(*lead, m, b)
-    mag = jnp.abs(xb)
-    _, order = jax.lax.top_k(mag, k_per_bucket)              # (*lead, m, k)
-    lidx = jnp.sort(order, axis=-1).astype(jnp.int32)
-    val = jnp.take_along_axis(xb, lidx, axis=-1)
-    iota = jnp.arange(b, dtype=jnp.int32)
-    sel = jnp.any(lidx[..., None] == iota, axis=-2)          # (*lead, m, b)
+    lidx, val, sel = _rounds(xb, k_per_bucket)
     residual = jnp.where(sel, 0, xb).reshape(*lead, cols)
     return BatchedStream(lidx, val, b), residual
