@@ -71,18 +71,19 @@ def probe(trainer, model, wl, cfgf, seed, devices) -> dict:
 
     from repro.train.train_step import _accumulated_grads
 
-    a, o = check.arch_of(cfgf), check.opt_of(wl)
+    mod = reference.arch_module(cfgf["reference"])
+    a, o = mod.arch(cfgf["model"]), check.opt_of(wl)
     rows = traffic.rows(seed, 0, wl["seqs_per_chip"] * wl["chips"],
-                        wl["seq_len"], a.vocab_size)
+                        wl["seq_len"], cfgf["model"]["vocab_size"])
     reseed(trainer, model, seed)
     batch = {"tokens": jnp.asarray(rows), "labels": jnp.asarray(rows)}
     grad = jax.jit(lambda p, b: _accumulated_grads(
         model, p, b, wl["microbatches"])[1])
     gp = run.flat_host(grad(trainer.state.params, batch))
     trainer.state = None
-    tr = reference.Trainer(seed, a, o, ranks=1, prec="f32")
-    _, acc = reference.rank_grads(tr.unflat(tr.params), rows, a, "f32",
-                                  devices[0])
+    tr = reference.Trainer(seed, mod, a, o, ranks=1, prec="f32")
+    _, acc = reference.rank_grads(tr.unflat(tr.params), rows, mod.seq_loss,
+                                  a, "f32", devices[0])
     gr = {k: np.asarray(v) / len(rows) for k, v in reference.flat(
         acc).items()}
     del acc
@@ -91,7 +92,7 @@ def probe(trainer, model, wl, cfgf, seed, devices) -> dict:
     out = {}
     for i, name in enumerate(tr.params):
         p, r = np.asarray(gp[name], np.float32), gr[name]
-        ax = reference.model_axis(name, p.ndim)
+        ax = tr.axis(name, p.ndim)
         res0 = jnp.zeros(reference.to_rows(jnp.asarray(r), ax, o.bucket).shape)
         kp, _ = reference.topk_ef(jnp.asarray(p), res0, 1.0, ax, o.k, o.bucket)
         kr, _ = reference.topk_ef(jnp.asarray(r), res0, 1.0, ax, o.k, o.bucket)

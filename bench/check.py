@@ -39,12 +39,6 @@ from bench import reference, traffic
 LIVE_FRACTION = 1e-3
 
 
-def arch_of(cfgf: dict) -> reference.Arch:
-    m = cfgf["model"]
-    fields = reference.Arch.__dataclass_fields__
-    return reference.Arch(**{k: v for k, v in m.items() if k in fields})
-
-
 def opt_of(wl: dict) -> reference.Opt:
     s, o = wl["sync"], wl["optimizer"]
     return reference.Opt(k=s["k_per_bucket"], bucket=s["bucket_size"],
@@ -59,18 +53,20 @@ def norm(x) -> float:
 
 def reference_readings(cfgf: dict, wl: dict, seed: int, devices,
                        prec: str = "f32", fault: str | None = None) -> dict:
-    """Readings of the reference over the cell's first steps. ``fault``
+    """Readings of the reference over the cell's first steps, the model
+    the configuration's ``"reference"`` module. ``fault``
     plants one in the reference put in the program's place:
     ``"half_batch"`` (each rank's mean over the first half of its rows)
     or ``"no_exchange"`` (each rank keeps its own gradient)."""
-    a, o = arch_of(cfgf), opt_of(wl)
+    mod = reference.arch_module(cfgf["reference"])
+    a, o = mod.arch(cfgf["model"]), opt_of(wl)
     chips = wl["chips"]
-    tr = reference.Trainer(seed, a, o, ranks=chips, prec=prec)
+    tr = reference.Trainer(seed, mod, a, o, ranks=chips, prec=prec)
     p0 = dict(tr.params)
     losses, gnorms, ef, g1 = [], [], [], None
     for step in range(wl["check_steps"]):
         rows = traffic.rows(seed, step, wl["seqs_per_chip"] * chips,
-                            wl["seq_len"], a.vocab_size)
+                            wl["seq_len"], cfgf["model"]["vocab_size"])
         by_rank = np.split(rows, chips)
         if fault == "half_batch":
             by_rank = [r[: len(r) // 2] for r in by_rank]

@@ -1,12 +1,14 @@
-"""Plain reference of one data-parallel training step with sparse sync.
+"""Plain reference of one data-parallel training step with sparse sync:
+what every model shares. It knows no model; each configuration file names
+its model's module under ``"reference"``, ``bench/archs/<name>.py``, found
+by :func:`arch_module`.
 
 Written from the published descriptions, in straightforward ``jax.numpy``,
 and independent of the program under test (it imports nothing of it):
 
-* the model: Mamba-2 (arXiv:2405.21060; SSD as a chunked scan, written
-  out), and the Zamba2-style hybrid (arXiv:2411.15242) that follows every
-  ``attn_every`` Mamba-2 layers with one shared attention + SwiGLU block;
-  next-token cross-entropy over the padded vocabulary;
+* the numerics the models' modules compute with: contractions
+  (:func:`ein`), stored activations (:func:`act`), :func:`rmsnorm`, and
+  the weights' draws (:func:`normal`);
 * the sync of SparCML's Quantized TopK SGD (paper Alg. 2): per rank, the
   error-fed gradient keeps its ``k`` largest entries of every
   ``bucket``-wide run of its layout; the kept entries are summed over the
@@ -15,9 +17,25 @@ and independent of the program under test (it imports nothing of it):
   draws); then clipping by global norm and AdamW with the warm-up /
   cosine schedule, parameters stored in their configured dtype.
 
-Weights are made from the seed as the configuration's initialiser makes
-them (same key splits and scales), so program and reference start from
-the same numbers without one taking them from the other.
+A model's module provides:
+
+* ``Arch`` and ``arch(model) -> Arch``: the sizes it needs, from the
+  configuration file's ``model`` block (a frozen dataclass: it is a
+  static argument of the jitted gradient);
+* ``init_params(seed, arch)``: weights from the seed as the program's
+  initialiser makes them (same key splits and scales), so program and
+  reference start from the same numbers without one taking them from the
+  other;
+* ``seq_loss(params, tokens, arch, prec)``: the mean next-token loss of
+  one sequence, its contractions through :func:`ein` and its stored
+  activations through :func:`act`;
+* ``flops_per_token(model, seq_len) -> {term: FLOPs, ..., "total"}``: the
+  model FLOPs of one training token that ``mfu`` counts, its terms in its
+  docstring;
+* optionally ``model_axis(path, ndim)``, where its leaves' bucket layout
+  differs from :func:`model_axis`;
+* ``TINY`` and ``FLOPS_CASES``: a tiny cell and hand counts of
+  ``flops_per_token`` for the benchmark's own tests.
 
 Precision: ``prec="f32"`` computes every contraction in float32 at
 ``Precision.HIGHEST``; ``prec="fp8"`` (the control) rounds where the
@@ -34,54 +52,41 @@ between steps and visit the device one leaf at a time.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+import sys
 from dataclasses import dataclass
 from functools import partial
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
+ARCHS = Path(__file__).resolve().parent / "archs"
 
 
-@dataclass(frozen=True)
-class Arch:
-    """The sizes the reference needs, read from the configuration file."""
-    family: str            # "ssm" | "hybrid"
-    num_layers: int
-    d_model: int
-    vocab_size: int
-    ssm_state: int
-    ssm_expand: int
-    ssm_head_dim: int
-    ssm_chunk: int
-    conv_width: int
-    num_heads: int = 1
-    num_kv_heads: int = 1
-    head_dim: int = 0
-    d_ff: int = 0
-    attn_every: int = 0
-    tie_embeddings: bool = False
-    norm_eps: float = 1e-5
-    rope_theta: float = 10000.0
-    param_dtype: str = "bfloat16"
+def arch_module(name: str):
+    """The model's reference module ``<ARCHS>/<name>.py``, the name a
+    configuration file gives under ``"reference"``; loaded once, so that
+    its functions stay the same static arguments of the jitted gradient."""
+    return _load(ARCHS / f"{name}.py")
 
-    @property
-    def padded_vocab(self) -> int:
-        return -(-self.vocab_size // 256) * 256
 
-    @property
-    def d_inner(self) -> int:
-        return self.ssm_expand * self.d_model
-
-    @property
-    def ssm_heads(self) -> int:
-        return self.d_inner // self.ssm_head_dim
-
-    @property
-    def pdtype(self):
-        return jnp.dtype(self.param_dtype)
+@functools.cache
+def _load(path: Path):
+    if not path.is_file():
+        raise FileNotFoundError(f"no reference module {path}: the "
+                                f"configuration's \"reference\" names "
+                                f"{path.stem!r}")
+    name = f"bench_arch:{path}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod        # a dataclass looks its module up there
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclass(frozen=True)
@@ -110,83 +115,9 @@ class Opt:
         return self.peak_lr * warm * frac
 
 
-# ------------------------------------------------------------ weights
-
-def _normal(key, shape, scale, dtype):
+def normal(key, shape, scale, dtype):
+    """N(0, scale^2) drawn in float32, stored in ``dtype``."""
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
-
-
-def _mamba_layer(key, a: Arch) -> dict:
-    d, di, n, h = a.d_model, a.d_inner, a.ssm_state, a.ssm_heads
-    ks = jax.random.split(key, 5)
-    conv_dim = di + 2 * n
-    return {
-        "ln": {"scale": jnp.ones((d,), a.pdtype)},
-        "mixer": {
-            "in_proj": _normal(ks[0], (d, 2 * di + 2 * n + h),
-                               1 / math.sqrt(d), a.pdtype),
-            "conv_w": _normal(ks[1], (a.conv_width, conv_dim),
-                              1 / math.sqrt(a.conv_width), a.pdtype),
-            "conv_b": jnp.zeros((conv_dim,), a.pdtype),
-            "A_log": jnp.log(jnp.linspace(1.0, 16.0, h)).astype(jnp.float32),
-            "D": jnp.ones((h,), jnp.float32),
-            "dt_bias": jnp.zeros((h,), jnp.float32),
-            "norm": {"scale": jnp.ones((di,), a.pdtype)},
-            "out_proj": _normal(ks[2], (di, d),
-                                1 / math.sqrt(di * 2 * a.num_layers), a.pdtype),
-        },
-    }
-
-
-def _shared_block(key, a: Arch) -> dict:
-    d, nh, nkv, hd, ff = (a.d_model, a.num_heads, a.num_kv_heads, a.head_dim,
-                          a.d_ff)
-    k1, k2 = jax.random.split(key)
-    ka = jax.random.split(k1, 4)
-    km = jax.random.split(k2, 3)
-    return {
-        "ln1": {"scale": jnp.ones((d,), a.pdtype)},
-        "attn": {
-            "wq": _normal(ka[0], (d, nh * hd), 1 / math.sqrt(d), a.pdtype),
-            "wk": _normal(ka[1], (d, nkv * hd), 1 / math.sqrt(d), a.pdtype),
-            "wv": _normal(ka[2], (d, nkv * hd), 1 / math.sqrt(d), a.pdtype),
-            "wo": _normal(ka[3], (nh * hd, d),
-                          1 / math.sqrt(nh * hd * 2 * a.num_layers), a.pdtype),
-        },
-        "ln2": {"scale": jnp.ones((d,), a.pdtype)},
-        "mlp": {
-            "wi": _normal(km[0], (d, ff), 1 / math.sqrt(d), a.pdtype),
-            "wo": _normal(km[1], (ff, d),
-                          1 / math.sqrt(ff * 2 * a.num_layers), a.pdtype),
-            "wg": _normal(km[2], (d, ff), 1 / math.sqrt(d), a.pdtype),
-        },
-    }
-
-
-def init_params(seed: int, a: Arch) -> dict:
-    """Weights from the seed, as the configuration's initialiser makes
-    them: embedding N(0, 0.02), projections N(0, 1/fan_in), output
-    projections scaled by 1/sqrt(2 * layers), norms at one, biases at
-    zero, A = -[1..16], D = 1; layers stacked on a leading axis."""
-    key = jax.random.PRNGKey(seed)
-    ks = jax.random.split(key, 8)
-    v, d = a.padded_vocab, a.d_model
-    p = {"embed": _normal(ks[0], (v, d), 0.02, a.pdtype),
-         "final_norm": {"scale": jnp.ones((d,), a.pdtype)}}
-    if not a.tie_embeddings:
-        p["unembed"] = _normal(ks[1], (d, v), d ** -0.5, a.pdtype)
-    layer = lambda k: _mamba_layer(k, a)
-    if a.family == "ssm":
-        p["blocks"] = jax.vmap(layer)(jax.random.split(ks[2], a.num_layers))
-    elif a.family == "hybrid":
-        nsb = a.num_layers // a.attn_every
-        p["blocks"] = jax.vmap(
-            lambda k: jax.vmap(layer)(jax.random.split(k, a.attn_every)))(
-                jax.random.split(ks[2], nsb))
-        p["shared_block"] = _shared_block(ks[3], a)
-    else:
-        raise ValueError(a.family)
-    return p
 
 
 # ------------------------------------------------------------ contractions
@@ -241,154 +172,26 @@ def ein(prec: str, eq: str, x, y):
     return act(prec, jnp.einsum(eq, x, y, precision=HI))
 
 
-# ------------------------------------------------------------ model
-
 def rmsnorm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def ssd(x, dt, a_log, b, c, d_skip, chunk: int, prec: str):
-    """y_t = sum_{s<=t} (C_t . B_s) exp(sum_{s<r<=t} dt_r A) dt_s x_s + D x_t
-    for one sequence, computed chunk by chunk. x: (S,H,P), dt: (S,H),
-    b/c: (S,N)."""
-    s, h, p = x.shape
-    nc = s // chunk
-    a = -jnp.exp(a_log)                                   # (H,)
-    da = dt * a                                           # (S,H)
-    xc = x.reshape(nc, chunk, h, p)
-    dtc = dt.reshape(nc, chunk, h)
-    dac = da.reshape(nc, chunk, h)
-    bc = b.reshape(nc, chunk, -1)
-    cc = c.reshape(nc, chunk, -1)
-    cum = jnp.cumsum(dac, axis=1)                         # (nc,Q,H)
-    # decay from position j to position i inside a chunk (i >= j)
-    seg = cum[:, :, None, :] - cum[:, None, :, :]         # (nc,Qi,Qj,H)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[None, :, :, None]
-    decay = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
-    cb = ein(prec, "cin,cjn->cij", cc, bc)                # (nc,Qi,Qj)
-    w = cb[..., None] * decay * dtc[:, None, :, :]        # (nc,Qi,Qj,H)
-    y_in = ein(prec, "cijh,cjhp->cihp", w, xc)
-    # state at the end of each chunk from its own inputs
-    to_end = jnp.exp(cum[:, -1:, :] - cum) * dtc          # (nc,Q,H)
-    st = ein(prec, "cjn,cjhp->chpn", bc, xc * to_end[..., None])
+# ------------------------------------------------------------ gradients
 
-    def carry(state, inp):
-        st_c, tot = inp
-        return state * jnp.exp(tot)[:, None, None] + st_c, state
-
-    _, entering = jax.lax.scan(carry, jnp.zeros((h, p, st.shape[-1])),
-                               (st, cum[:, -1, :]))       # (nc,H,P,N)
-    y_off = ein(prec, "cin,chpn->cihp", cc, entering) * jnp.exp(cum)[..., None]
-    y = (y_in + y_off).reshape(s, h, p)
-    return y + d_skip[None, :, None] * x
-
-
-def mamba_layer(lp, a: Arch, x, prec):
-    """One residual Mamba-2 layer on (S, d)."""
-    s = x.shape[0]
-    di, n, h, hp = a.d_inner, a.ssm_state, a.ssm_heads, a.ssm_head_dim
-    m = lp["mixer"]
-    u = rmsnorm(x, lp["ln"]["scale"], a.norm_eps)
-    zxbcdt = ein(prec, "sd,de->se", u, m["in_proj"])
-    z = zxbcdt[:, :di]
-    xbc = zxbcdt[:, di:2 * di + 2 * n]
-    dt = zxbcdt[:, 2 * di + 2 * n:]
-    w = m["conv_w"].astype(jnp.float32)
-    width = w.shape[0]
-    pad = jnp.pad(xbc, ((width - 1, 0), (0, 0)))
-    conv = sum(pad[i:i + s] * w[i] for i in range(width))
-    xbc = act(prec, jax.nn.silu(conv + m["conv_b"].astype(jnp.float32)))
-    xs, b, c = xbc[:, :di], xbc[:, di:di + n], xbc[:, di + n:]
-    dt = jax.nn.softplus(dt + m["dt_bias"])
-    y = ssd(xs.reshape(s, h, hp), dt, m["A_log"], b, c, m["D"], a.ssm_chunk,
-            prec).reshape(s, di)
-    y = rmsnorm(y * jax.nn.silu(z), m["norm"]["scale"], a.norm_eps)
-    return act(prec, x + ein(prec, "se,ed->sd", y, m["out_proj"]))
-
-
-def rope(x, theta):
-    s, _, hd = x.shape
-    half = hd // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
-                    / half)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None, None] * freqs
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
-                            x2 * jnp.cos(ang) + x1 * jnp.sin(ang)], axis=-1)
-
-
-def shared_block(bp, a: Arch, x, prec, q_block: int = 1024):
-    """Causal multi-head attention with rotary positions, then SwiGLU;
-    both pre-norm and residual. Queries go in blocks of ``q_block``."""
-    s = x.shape[0]
-    nh, nkv, hd = a.num_heads, a.num_kv_heads, a.head_dim
-    at = bp["attn"]
-    u = rmsnorm(x, bp["ln1"]["scale"], a.norm_eps)
-    q = rope(ein(prec, "sd,de->se", u, at["wq"]).reshape(s, nh, hd),
-             a.rope_theta)
-    k = rope(ein(prec, "sd,de->se", u, at["wk"]).reshape(s, nkv, hd),
-             a.rope_theta)
-    v = ein(prec, "sd,de->se", u, at["wv"]).reshape(s, nkv, hd)
-    k = jnp.repeat(k, nh // nkv, axis=1)
-    v = jnp.repeat(v, nh // nkv, axis=1)
-    qb = min(q_block, s)
-
-    def block(i):
-        qi = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, axis=0)
-        sc = ein(prec, "qhe,khe->hqk", qi, k) / math.sqrt(hd)
-        pos_q = i * qb + jnp.arange(qb)[:, None]
-        mask = pos_q >= jnp.arange(s)[None, :]
-        sc = jnp.where(mask[None], sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        return ein(prec, "hqk,khe->qhe", pr, v)
-
-    o = jax.lax.map(jax.checkpoint(block), jnp.arange(s // qb))
-    o = o.reshape(s, nh * hd)
-    x = act(prec, x + ein(prec, "se,ed->sd", o, at["wo"]))
-    m = bp["mlp"]
-    u = rmsnorm(x, bp["ln2"]["scale"], a.norm_eps)
-    hmid = (ein(prec, "sd,df->sf", u, m["wi"])
-            * jax.nn.silu(ein(prec, "sd,df->sf", u, m["wg"])))
-    return act(prec, x + ein(prec, "sf,fd->sd", hmid, m["wo"]))
-
-
-def seq_loss(params, tokens, a: Arch, prec: str):
-    """Mean next-token cross-entropy of one sequence (S,) int32."""
-    x = act(prec, params["embed"][tokens].astype(jnp.float32))
-    layer = jax.checkpoint(lambda h, lp: (mamba_layer(lp, a, h, prec), None))
-    if a.family == "ssm":
-        x, _ = jax.lax.scan(layer, x, params["blocks"])
-    else:
-        sb = params["shared_block"]
-        blk = jax.checkpoint(lambda h: shared_block(sb, a, h, prec))
-
-        def superblock(h, sbp):
-            h, _ = jax.lax.scan(layer, h, sbp)
-            return blk(h), None
-
-        x, _ = jax.lax.scan(superblock, x, params["blocks"])
-    x = rmsnorm(x, params["final_norm"]["scale"], a.norm_eps)
-    if a.tie_embeddings:
-        logits = ein(prec, "sd,vd->sv", x, params["embed"])
-    else:
-        logits = ein(prec, "sd,dv->sv", x, params["unembed"])
-    logp = jax.nn.log_softmax(logits[:-1], axis=-1)
-    ll = jnp.take_along_axis(logp, tokens[1:, None], axis=-1)[:, 0]
-    return -jnp.mean(ll)
-
-
-@partial(jax.jit, static_argnames=("a", "prec"), donate_argnums=(2,))
-def _seq_grad(params, tokens, acc, a: Arch, prec: str):
+@partial(jax.jit, static_argnames=("seq_loss", "a", "prec"),
+         donate_argnums=(2,))
+def _seq_grad(params, tokens, acc, seq_loss, a, prec: str):
     p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
     loss, g = jax.value_and_grad(seq_loss)(p32, tokens, a, prec)
     return loss, jax.tree.map(jnp.add, acc, g)
 
 
-def rank_grads(params, rows, a: Arch, prec: str, device=None):
-    """Losses and summed f32 gradient over the sequences ``rows`` (R, S)
-    of one rank, one sequence at a time, dispatched without waiting (so
-    ranks on different devices run side by side)."""
+def rank_grads(params, rows, seq_loss, a, prec: str, device=None):
+    """Losses and summed f32 gradient of the model's ``seq_loss`` over the
+    sequences ``rows`` (R, S) of one rank, one sequence at a time,
+    dispatched without waiting (so ranks on different devices run side by
+    side)."""
     params = jax.device_put(params, device)
     acc = jax.device_put(
         jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
@@ -396,7 +199,7 @@ def rank_grads(params, rows, a: Arch, prec: str, device=None):
     losses = []
     for r in rows:
         loss, acc = _seq_grad(params, jax.device_put(jnp.asarray(r), device),
-                              acc, a, prec)
+                              acc, seq_loss, a, prec)
         losses.append(loss)
     return losses, acc
 
@@ -485,18 +288,20 @@ def flat(tree) -> dict:
 
 
 class Trainer:
-    """The reference's training state for ``ranks`` data-parallel ranks:
+    """The reference's training state for ``ranks`` data-parallel ranks of
+    the model ``mod`` (its module, :func:`arch_module`) at sizes ``a``:
     parameters (configured dtype), per-rank error-feedback residuals and
     AdamW moments (float32), all on the host between steps."""
 
-    def __init__(self, seed: int, a: Arch, o: Opt, ranks: int, prec: str):
-        self.a, self.o, self.ranks, self.prec = a, o, ranks, prec
+    def __init__(self, seed: int, mod, a, o: Opt, ranks: int, prec: str):
+        self.mod, self.a, self.o, self.ranks, self.prec = mod, a, o, ranks, prec
         self.seed = seed
+        self.axis = getattr(mod, "model_axis", model_axis)
         self.params = {k: np.asarray(v) for k, v in
-                       flat(jax.jit(init_params, static_argnums=(0, 1))(
+                       flat(jax.jit(mod.init_params, static_argnums=(0, 1))(
                            seed, a)).items()}
         self.tree = jax.tree.structure(
-            jax.eval_shape(lambda: init_params(seed, a)))
+            jax.eval_shape(lambda: mod.init_params(seed, a)))
         self.res = {k: [None] * ranks for k in self.params}
         self.m = {k: None for k in self.params}
         self.v = {k: None for k in self.params}
@@ -513,7 +318,7 @@ class Trainer:
         step, over all ranks."""
         a, o = self.a, self.o
         params = self.unflat(self.params)
-        futs = [rank_grads(params, rows, a, self.prec,
+        futs = [rank_grads(params, rows, self.mod.seq_loss, a, self.prec,
                            devices[r % len(devices)])
                 for r, rows in enumerate(rows_by_rank)]
         n = sum(len(rows) for rows in rows_by_rank)
@@ -526,7 +331,7 @@ class Trainer:
         synced, sq, ef_sq = {}, 0.0, 0.0
         for i, name in enumerate(self.params):
             shape = self.params[name].shape
-            ax = model_axis(name, len(shape))
+            ax = self.axis(name, len(shape))
             kept_sum = None
             for r in range(self.ranks):
                 g = grads[r][name]

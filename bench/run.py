@@ -3,7 +3,9 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is ``bench/workloads/<cell>.json``; it names its configuration,
-``bench/configs/<config>.json``. One process owns all the chips, and the
+``bench/configs/<config>.json``, which names its model's plain reference,
+``bench/archs/<reference>.py`` (a missing one fails at the start). One
+process owns all the chips, and the
 run refuses (exit 2, no result) unless JAX sees TPUs, as many as the cell
 asks for. It keeps JAX's compile cache where
 ``repro.launch.compile_cache.enable_compile_cache`` puts it (inside the
@@ -16,13 +18,16 @@ call the window uses; the first compiles. Window: ``Trainer.run(step + 1)`` unti
 ``--seconds`` have passed; ``tokens_per_s`` is the tokens of those whole
 steps over first dispatch to last ``block_until_ready``. After the
 window: peak device memory, then the program's state is freed and the
-plain reference (``bench/reference.py``) replays those first steps from
-the same seed; ``correct`` holds when each compared number is within
-the cell's limit (``bench/check.py``).
+plain reference (``bench/reference.py`` with the model's module) replays
+those first steps from the same seed; ``correct`` holds when each
+compared number is within the cell's limit (``bench/check.py``).
 
-``--trace 1`` traces the window with ``jax.profiler`` under
-``bench/traces/`` and reports the cell's per-layer metrics, each computed
-by its reader in ``bench/metrics/<metric>.py`` from the reduced trace.
+``--trace 1`` runs the program with its own tracing on (the ``train/*``
+spans of ``Trainer.run``), takes the step's ``{instruction: scope}`` map
+from the executable the window runs (:func:`step_scopes`), traces the
+window with ``jax.profiler`` under ``bench/traces/``, reduces it with
+``bench/scope_reduce.py`` and reports the cell's per-layer metrics, each
+computed by its reader in ``bench/metrics/<metric>.py``.
 
 The last line of stdout is one JSON object; the compared numbers with
 their limits are also the last lines of stderr.
@@ -45,6 +50,7 @@ from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+METRICS = BENCH / "metrics"
 for _p in (str(ROOT / "src"), str(ROOT)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
@@ -55,9 +61,13 @@ def load_json(path: Path) -> dict:
 
 
 def load_cell(name: str) -> tuple[dict, dict]:
-    """(workload, configuration) of a cell, found by name."""
+    """(workload, configuration) of a cell, found by name; raises where the
+    configuration's reference module is missing."""
+    from bench import reference
+
     wl = load_json(BENCH / "workloads" / f"{name}.json")
     cfg = load_json(BENCH / "configs" / f"{wl['config']}.json")
+    reference.arch_module(cfg["reference"])
     return wl, cfg
 
 
@@ -72,7 +82,7 @@ def per_layer_metrics(cell: str) -> list[dict]:
 
 def metric_reader(name: str):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py")
+        f"bench_metric_{name}", METRICS / f"{name}.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
@@ -231,6 +241,59 @@ def first_steps(trainer, n_check: int) -> dict:
             "gnorms": [float(x) for x in sn.norms], "ef": ef}
 
 
+def step_text(step_fn, *args) -> str:
+    """The compiled step's text, from a compile with the persistent
+    compilation cache off. That cache keys a module without its
+    ``op_name`` metadata, so a hit returns the text of whichever program
+    wrote the entry: another commit's scopes, or none. Called before the
+    step first runs, the compile also serves the step's calls (JAX's
+    in-memory cache holds it), so nothing compiles twice."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return step_fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def step_scopes(trainer) -> dict[str, str]:
+    """``{instruction: scope}`` of the step executable that ``trainer``'s
+    calls run, compiled here for the arguments of its first call as
+    ``Trainer.run`` makes them."""
+    import jax
+
+    from repro.data.pipeline import synthetic_batch
+    from repro.obs.scopes import op_scopes
+
+    batch = jax.tree.map(jax.numpy.asarray,
+                         synthetic_batch(trainer.data_cfg, 0))
+    key = jax.random.fold_in(trainer._root_key, 0)
+    with trainer.mesh:
+        return op_scopes(step_text(trainer.step_fn, trainer.state, batch,
+                                   key))
+
+
+def window(trainer, seconds: float) -> tuple[int, float, float]:
+    """The measured window: ``Trainer.run(step + 1)`` under a ``step``
+    span until ``seconds`` have passed; (steps, start, end) on the host
+    clock, the end after the last step's ``block_until_ready``."""
+    import jax
+
+    t0 = time.perf_counter()
+    steps = 0
+    while True:
+        with jax.profiler.TraceAnnotation("step"):
+            trainer.run(int(trainer.state.step) + 1)
+        steps += 1
+        if time.perf_counter() - t0 >= seconds:
+            return steps, t0, time.perf_counter()
+
+
 def readings(first: dict) -> dict:
     return {"losses": first["losses"], "gnorms": first["gnorms"],
             "ef": first["ef"], "g1": first["g1"],
@@ -274,8 +337,8 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
     Returns the result object (``checks`` last)."""
     import jax
 
-    from bench import check, trace_reduce
-    from bench.flops import flops_per_token
+    import repro.obs
+    from bench import check, reference, scope_reduce
     from repro.launch.mesh import make_host_mesh
     from repro.train import trainer as trainer_mod
 
@@ -284,8 +347,11 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
     model, tcfg, data = build_program(wl, cfgf, seed, chips)
     mesh = make_host_mesh(data=chips)
     check_plan(model, tcfg, mesh)
-    trainer = trainer_mod.Trainer(model, tcfg, mesh, data)
+    obs = repro.obs.configure(trace=True, set_as_default=False) \
+        if trace else None
+    trainer = trainer_mod.Trainer(model, tcfg, mesh, data, obs=obs)
     trainer.init_or_resume()
+    op_scope = step_scopes(trainer) if trace else None
     log = trainer.log
     n_check = wl["check_steps"]
     with annotated(trainer_mod, trainer, fault):
@@ -301,18 +367,13 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
             opts.host_tracer_level = 2
             jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
         compiles_before = len(clog.names)
-        t0 = time.perf_counter()
+        steps, t0, t1 = window(trainer, seconds)
         setup_s = t0 - t_start
-        steps = 0
-        while True:
-            with jax.profiler.TraceAnnotation("step"):
-                trainer.run(int(trainer.state.step) + 1)
-            steps += 1
-            if time.perf_counter() - t0 >= seconds:
-                break
-        t1 = time.perf_counter()
         if trace:
+            t_stop = time.perf_counter()
             jax.profiler.stop_trace()
+            print(f"bench: trace written in "
+                  f"{time.perf_counter() - t_stop:.1f} s", file=sys.stderr)
     window_compiles = clog.names[compiles_before:]
     clog.close()
     window_losses = log.losses[n_check:]
@@ -335,8 +396,13 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
         metrics["peak_hbm_gb"] = {"value": peak / 1e9, "unit": "GB"}
         metrics["setup_s"] = {"value": setup_s, "unit": "s"}
     else:
-        files = sorted(trace_dir.glob("**/*.xplane.pb"))
-        red = trace_reduce.reduce_trace(str(files[-1]))
+        t_read = time.perf_counter()
+        ev, spans = scope_reduce.load(
+            str(sorted(trace_dir.glob("**/*.xplane.pb"))[-1]))
+        red = scope_reduce.reduce_with_program(ev, spans, op_scope)
+        del ev, spans
+        print(f"bench: trace read and reduced in "
+              f"{time.perf_counter() - t_read:.1f} s", file=sys.stderr)
         out_dir = BENCH / "out"
         out_dir.mkdir(exist_ok=True)
         (out_dir / f"{name}.{seed}.trace.json").write_text(
@@ -346,8 +412,9 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
 
         facts = {"chips": chips, "steps": steps,
                  "tokens_per_step": tokens_per_step,
-                 "flops_per_token": flops_per_token(
-                     cfgf["model"], wl["seq_len"])["total"],
+                 "flops_per_token": reference.arch_module(
+                     cfgf["reference"]).flops_per_token(
+                         cfgf["model"], wl["seq_len"])["total"],
                  "peak_flops_per_s": peak_of(devices[0].device_kind)[
                      "bf16_flops_per_s"],
                  "host_window_s": t1 - t0}
@@ -361,7 +428,10 @@ def run_cell(name: str, wl: dict, cfgf: dict, seed: int, seconds: float,
                                     list(red.get("op_s", {}).items())[:10]],
                      "idle_gaps": red.get("idle_gaps", [])[:10]}
 
+    t_ref = time.perf_counter()
     ref = check.reference_readings(cfgf, wl, seed, devices)
+    print(f"bench: reference in {time.perf_counter() - t_ref:.1f} s",
+          file=sys.stderr)
     checks = check.compare(prog, ref, wl["limits"])
     checks["window_compiles"] = {"value": len(window_compiles), "limit": 0}
     correct = all(c["value"] <= c["limit"] for c in checks.values())

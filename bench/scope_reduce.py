@@ -7,6 +7,10 @@ scope of the train step, and the ``train/*`` host spans of
   each op charged to its innermost scope through the compiled step's
   ``{instruction: scope}`` map (``repro.obs.scopes.op_scopes``), inside
   the window and averaged over devices, as ``op_s`` counts;
+* ``scope_incl_s``: each scope's self time plus that of every scope
+  nested under its name (``model`` holds ``model/attention``; ``sync``
+  holds ``sync/compress`` and ``sync/qsgd``), so a reader of a scope keeps
+  its meaning when a later step names parts inside it;
 * ``unscoped_s``: the rest of that self time, ops no scope names;
 * ``host_span_s`` and ``host_span_n``: total length (clipped to the
   window) and count of each ``train/*`` span inside the window.
@@ -25,10 +29,14 @@ most of its time:
 * ``idle_by_span``: the whole idle time of the window split the same way,
   instant by instant, over every gap ("none" where no span is open).
 
-The readers ``bench/metrics/{model,compress,qsgd,update,host_step}_ms.py``
-take these keys; ``bench/tools/scope_trace.py`` writes them on the chip.
+``bench/run.py --trace 1`` reads its trace with :func:`load` and reduces
+its window with :func:`reduce_with_program`; the readers
+``bench/metrics/{model,compress,qsgd,update,host_step}_ms.py`` take these
+keys.
 """
 from __future__ import annotations
+
+import bisect
 
 from bench import trace_reduce as tr
 
@@ -36,40 +44,43 @@ PROGRAM_SPANS = ("train/run", "train/batch", "train/dispatch", "train/wait",
                  "train/log", "train/checkpoint")
 
 
-def load_program_spans(path: str) -> list[tuple[str, float, float]]:
-    """(name, start_ns, dur_ns) of the program's ``train/*`` spans on the
-    host plane of a trace file, from any thread line."""
-    import jax
-
-    pd = jax.profiler.ProfileData.from_file(path)
-    return [(e.name, float(e.start_ns), float(e.duration_ns))
-            for plane in pd.planes if plane.name == "/host:CPU"
-            for line in plane.lines for e in line.events
-            if e.name in PROGRAM_SPANS]
+def load(path: str) -> tuple[dict, list[tuple[str, float, float]]]:
+    """The trace file read once: ``trace_reduce.load``'s events, and the
+    (name, start_ns, dur_ns) of the program's ``train/*`` spans on the
+    host plane, from any thread line."""
+    ev = tr.load(path, tr.HOST_SPANS + PROGRAM_SPANS)
+    spans = [h for h in ev["host"] if h[0] in PROGRAM_SPANS]
+    ev["host"] = [h for h in ev["host"] if h[0] in tr.HOST_SPANS]
+    return ev, spans
 
 
-def scope_times(devices: dict, op_scope: dict[str, str],
-                window: tuple[float, float]) -> dict:
-    """``scope_s`` and ``unscoped_s`` of the device events ``devices``
-    ({device: [(hlo text, start_ns, dur_ns)]}, as ``trace_reduce.load``
-    gives them) in ``window``; ``op_scope`` maps instruction names to
-    scopes."""
-    devs = {k: v for k, v in devices.items() if v}
-    lo, hi = window
+def scope_times(op_s: dict[str, float], op_scope: dict[str, str]) -> dict:
+    """``scope_s``, ``scope_incl_s`` and ``unscoped_s`` from the device
+    self time per instruction, ``op_s`` of ``trace_reduce.reduce_events``
+    (inside the window, averaged over devices); ``op_scope`` maps
+    instruction names to scopes."""
     scope_s: dict[str, float] = {}
     unscoped = 0.0
-    for evs in devs.values():
-        for t, s, d, own in tr.self_times(evs):
-            if s + d <= lo or s >= hi:
-                continue
-            sec = own * 1e-9 / len(devs)
-            name = op_scope.get(tr.op_name(t))
-            if name is None:
-                unscoped += sec
-            else:
-                scope_s[name] = scope_s.get(name, 0.0) + sec
+    for op, sec in op_s.items():
+        name = op_scope.get(op)
+        if name is None:
+            unscoped += sec
+        else:
+            scope_s[name] = scope_s.get(name, 0.0) + sec
     return {"scope_s": dict(sorted(scope_s.items(), key=lambda kv: -kv[1])),
-            "unscoped_s": unscoped}
+            "scope_incl_s": inclusive(scope_s), "unscoped_s": unscoped}
+
+
+def inclusive(scope_s: dict[str, float]) -> dict[str, float]:
+    """Each scope and each enclosing name (``a`` and ``a/b`` of
+    ``a/b/c``) with the self time of every scope at or under it."""
+    out: dict[str, float] = {}
+    for name, secs in scope_s.items():
+        parts = name.split("/")
+        for i in range(1, len(parts) + 1):
+            key = "/".join(parts[:i])
+            out[key] = out.get(key, 0.0) + secs
+    return dict(sorted(out.items()))
 
 
 def host_span_times(spans, window: tuple[float, float]) -> dict:
@@ -113,10 +124,16 @@ def idle_names(devices: dict, spans, window: tuple[float, float],
         all_gaps = tr.gaps(busy, lo, hi)
         if all_gaps:
             break
+    # a span open in a gap starts less than the longest span before its end
+    spans = sorted(spans, key=lambda x: x[1])
+    starts = [s for _, s, _ in spans]
+    longest_span = max((d for _, _, d in spans), default=0.0)
     by_span: dict[str, float] = {}
     named = []
     for g in all_gaps:
-        split = innermost_time(g, spans)
+        near = spans[bisect.bisect_left(starts, g[0] - longest_span):
+                     bisect.bisect_left(starts, g[1])]
+        split = innermost_time(g, near)
         for n, secs in split.items():
             by_span[n] = by_span.get(n, 0.0) + secs
         named.append((max(split.items(), key=lambda kv: kv[1])[0], g))
@@ -138,7 +155,7 @@ def reduce_with_program(ev: dict, spans, op_scope: dict[str, str],
     red.update(host_span_times(spans, window))
     if red.get("n_devices"):
         devs = {k: v for k, v in ev["devices"].items() if v}
-        red.update(scope_times(devs, op_scope, window))
+        red.update(scope_times(red["op_s"], op_scope))
         red.update(idle_names(devs, list(ev["host"]) + list(spans), window,
                               top=top))
     return red

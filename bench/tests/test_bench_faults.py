@@ -7,6 +7,8 @@ taken over the rest, and (on four devices) the exchange between chips
 left out; and the control, the reference computed in float8, in the
 program's place, comes out not correct too.
 
+Each model's reference module (``bench/archs/<name>.py``) brings its tiny
+cell, ``TINY``: the sound run and the control run on every one of them.
 The tiny cells run in float32 without QSGD (whose stochastic rounding
 draws differ between program and reference), so the limits here are
 tight: a sound run reads about 1e-7."""
@@ -23,55 +25,57 @@ import pytest  # noqa: E402
 
 BENCH = Path(__file__).resolve().parents[1]
 TINY_LIMIT = 1e-4
-SSM = {"family": "ssm", "num_layers": 2, "d_model": 64, "vocab_size": 512,
-       "ssm_state": 16, "ssm_expand": 2, "ssm_head_dim": 16, "ssm_chunk": 8,
-       "conv_width": 4, "tie_embeddings": True, "norm_eps": 1e-5,
-       "dtype": "float32", "param_dtype": "float32"}
-HYBRID = {**SSM, "family": "hybrid", "num_layers": 4, "num_heads": 4,
-          "num_kv_heads": 4, "head_dim": 16, "d_ff": 128, "attn_every": 2,
-          "tie_embeddings": False, "rope_theta": 10000.0}
+ARCHS = sorted(p.stem for p in (BENCH / "archs").glob("*.py"))
 
 
-def tiny_cell(model: dict, chips: int):
-    fam = model["family"]
+def family(ref: str) -> str:
+    from bench.reference import arch_module
+
+    return arch_module(ref).TINY["model"]["family"]
+
+
+def tiny_cell(ref: str, chips: int):
+    """The tiny cell of the reference module ``ref`` on ``chips`` devices:
+    the one-chip cell's sync and optimizer at the learning rate the
+    program's train config states, float32, no QSGD, tight limits."""
+    from bench.reference import arch_module
+    from repro import configs
+
+    tiny = arch_module(ref).TINY
+    model = copy.deepcopy(tiny["model"])
     wl = json.loads((BENCH / "workloads" / "mamba2-370m-l2.topk-qsgd.1chip.json")
                     .read_text())
     wl.update(chips=chips, seq_len=32, seqs_per_chip=4, microbatches=2,
               check_steps=2)
-    # the learning rate each architecture's train config states
-    wl["optimizer"] = {**wl["optimizer"],
-                       "peak_lr": {"ssm": 6e-4, "hybrid": 3e-4}[fam]}
+    lr = configs.get_train_config(tiny["arch"]).schedule.peak_lr
+    wl["optimizer"] = {**wl["optimizer"], "peak_lr": lr}
     wl["sync"] = {**wl["sync"], "qsgd_bits": None, "min_sparse_size": 0}
     wl["limits"] = dict.fromkeys(
         ("loss_gap", "ef_gap", "gnorm_gap", "grad_gap", "grad_med",
          "change_gap", "change_med"),
         TINY_LIMIT)
-    arch = {"ssm": "mamba2_370m", "hybrid": "zamba2_2p7b"}[fam]
-    keep = ("num_layers", "d_model", "vocab_size", "ssm_state",
-            "ssm_head_dim", "ssm_chunk", "num_heads", "num_kv_heads",
-            "head_dim", "d_ff", "attn_every", "dtype", "param_dtype")
-    cfgf = {"name": f"tiny-{fam}", "arch": arch, "model": copy.deepcopy(model),
-            "overrides": {**{k: model[k] for k in keep if k in model},
-                          "max_seq_len": 128}}
+    cfgf = {"name": f"tiny-{ref}", "arch": tiny["arch"], "reference": ref,
+            "model": model, "overrides": {**model, "max_seq_len": 128}}
     return wl, cfgf
 
 
-def run_tiny(model, chips, fault=None):
+def run_tiny(ref, chips, fault=None):
     import jax
 
     from bench.run import run_cell
 
-    wl, cfgf = tiny_cell(model, chips)
+    wl, cfgf = tiny_cell(ref, chips)
     # a zero-second window runs one step
-    return run_cell(f"tiny-{model['family']}", wl, cfgf, 2**31 + 11, 0.0,
+    return run_cell(cfgf["name"], wl, cfgf, 2**31 + 11, 0.0,
                     False, jax.devices()[:chips], time.perf_counter(),
                     fault=fault)
 
 
-@pytest.mark.parametrize("model,chips", [(SSM, 1), (HYBRID, 1), (SSM, 4)],
-                         ids=["ssm-1", "hybrid-1", "ssm-4"])
-def test_sound_run_is_correct(model, chips):
-    res = run_tiny(model, chips)
+@pytest.mark.parametrize("ref,chips", [(r, c) for c in (1, 4) for r in ARCHS],
+                         ids=lambda v: family(v) if isinstance(v, str)
+                         else str(v))
+def test_sound_run_is_correct(ref, chips):
+    res = run_tiny(ref, chips)
     assert res["correct"], res["checks"]
     assert list(res)[-1] == "checks"
     assert res["attempted"] >= 1 and res["failed"] == 0
@@ -97,29 +101,29 @@ def half_batch(step_fn):
 
 
 def test_fault_state_unchanged():
-    res = run_tiny(SSM, 1, fault=state_unchanged)
+    res = run_tiny("mamba2", 1, fault=state_unchanged)
     assert not res["correct"]
     assert res["checks"]["change_gap"]["value"] == pytest.approx(1.0)
     assert res["checks"]["change_med"]["value"] == pytest.approx(1.0)
 
 
 def test_fault_half_batch():
-    res = run_tiny(SSM, 1, fault=half_batch)
+    res = run_tiny("mamba2", 1, fault=half_batch)
     assert not res["correct"]
     assert res["checks"]["loss_gap"]["value"] > TINY_LIMIT
     assert res["checks"]["ef_gap"]["value"] > TINY_LIMIT
     assert res["checks"]["grad_gap"]["value"] > TINY_LIMIT
 
 
-@pytest.mark.parametrize("model", [SSM, HYBRID], ids=["ssm", "hybrid"])
-def test_control_fp8_is_not_correct(model):
+@pytest.mark.parametrize("ref", ARCHS, ids=family)
+def test_control_fp8_is_not_correct(ref):
     """The control: the reference computed in float8 where the program
     computes in its own precision, put in the program's place."""
     import jax
 
     from bench import check
 
-    wl, cfgf = tiny_cell(model, 1)
+    wl, cfgf = tiny_cell(ref, 1)
     seed, devices = 2**31 + 11, jax.devices()[:1]
     ref = check.reference_readings(cfgf, wl, seed, devices)
     control = check.reference_readings(cfgf, wl, seed, devices, prec="fp8")
@@ -140,6 +144,6 @@ def test_fault_exchange_left_out(monkeypatch):
     monkeypatch.setattr(
         CollectiveContext, "all_gather",
         lambda self, x, *, axis: jnp.concatenate([x] * self.p, axis=axis))
-    res = run_tiny(SSM, 4)
+    res = run_tiny("mamba2", 4)
     assert not res["correct"]
     assert res["checks"]["grad_gap"]["value"] > TINY_LIMIT

@@ -1,8 +1,9 @@
 """The reduction of the program's own marks (``bench/scope_reduce.py``) on
-hand-made events, the readers that take it, and the trace tool that writes
-it (``bench/tools/scope_trace.py``) at a tiny size on the CPU."""
+hand-made events and on a recorded trace, the readers that take it, and
+``bench/run.py``'s traced path at a tiny size on the CPU."""
 import importlib.util
 import os
+import random
 from pathlib import Path
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -18,10 +19,10 @@ BODY = "%fusion.2 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop"
 SORT = "%sort.4 = f32[64]{0} sort(f32[64]{0} %b), dimensions={0}"
 COPY = "%copy.9 = s32[] copy(s32[] %c)"
 SCOPE = {"while.7": "model", "fusion.2": "model", "sort.4": "sync/compress"}
-READERS = {"model_ms": ("scope_s", "model"),
-           "compress_ms": ("scope_s", "sync/compress"),
-           "qsgd_ms": ("scope_s", "sync/qsgd"),
-           "update_ms": ("scope_s", "update"),
+READERS = {"model_ms": ("scope_incl_s", "model"),
+           "compress_ms": ("scope_incl_s", "sync/compress"),
+           "qsgd_ms": ("scope_incl_s", "sync/qsgd"),
+           "update_ms": ("scope_incl_s", "update"),
            "host_step_ms": ("host_span_s", "train/run")}
 
 
@@ -36,6 +37,13 @@ def reader(name):
     return load(f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py").read
 
 
+def scope_times(devices, op_scope, window):
+    """``scope_times`` of the self times ``reduce_events`` finds."""
+    op_s = tr.reduce_events({"devices": devices, "async": {}, "host": []},
+                            window)["op_s"]
+    return sr.scope_times(op_s, op_scope)
+
+
 def test_scope_times_charge_innermost_self_time():
     devices = {
         # a loop (model) whose body fusion (model) runs 10-40 and 50-70,
@@ -44,16 +52,40 @@ def test_scope_times_charge_innermost_self_time():
                           (SORT, 120, 10), (COPY, 140, 10)],
         "/device:TPU:1": [(SORT, 0, 40), (COPY, 200, 10)],
         "/device:TPU:2": []}
-    out = sr.scope_times(devices, SCOPE, (0, 300))
+    out = scope_times(devices, SCOPE, (0, 300))
     # averaged over the two devices with events
     assert out["scope_s"]["model"] == pytest.approx(100e-9 / 2)
     assert out["scope_s"]["sync/compress"] == pytest.approx(50e-9 / 2)
     assert out["unscoped_s"] == pytest.approx(20e-9 / 2)
     assert list(out["scope_s"]) == ["model", "sync/compress"]
+    # sync has no op of its own here, and holds sync/compress
+    assert out["scope_incl_s"] == {"model": pytest.approx(100e-9 / 2),
+                                   "sync": pytest.approx(50e-9 / 2),
+                                   "sync/compress": pytest.approx(50e-9 / 2)}
     # an op wholly outside the window is left out, as op_s leaves it out
-    out = sr.scope_times(devices, SCOPE, (100, 300))
+    out = scope_times(devices, SCOPE, (100, 300))
     assert "model" not in out["scope_s"]
     assert out["scope_s"]["sync/compress"] == pytest.approx(10e-9 / 2)
+
+
+def test_nested_scope_leaves_model_ms_whole():
+    """A later model that names ``model/x`` inside ``model`` moves time
+    out of ``model``'s self time, and ``model_ms`` still reads the whole
+    model."""
+    inner = "%fusion.5 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop"
+    devices = {"/device:TPU:0": [(LOOP, 0, 100), (BODY, 10, 30),
+                                 (inner, 50, 20), (SORT, 120, 10)]}
+    flat = scope_times(devices, {**SCOPE, "fusion.5": "model"}, (0, 300))
+    nested = scope_times(devices, {**SCOPE, "fusion.5": "model/x"},
+                            (0, 300))
+    assert nested["scope_s"]["model"] == pytest.approx(80e-9)
+    assert nested["scope_s"]["model/x"] == pytest.approx(20e-9)
+    assert nested["scope_incl_s"]["model"] == pytest.approx(100e-9)
+    facts = {"chips": 1, "steps": 1}
+    assert reader("model_ms")(nested, facts) == pytest.approx(
+        reader("model_ms")(flat, facts)) == pytest.approx(1e-4)
+    assert sr.inclusive({"a/b/c": 1.0, "a": 2.0, "ab": 4.0}) == {
+        "a": 3.0, "a/b": 1.0, "a/b/c": 1.0, "ab": 4.0}
 
 
 def test_host_span_times_clip_to_window():
@@ -125,30 +157,77 @@ def test_new_readers(name):
     assert read(red, {"chips": 1, "steps": 0}) is None
 
 
-def test_scope_trace_tool_on_cpu(tmp_path):
-    """The trace tool end to end at a tiny size: the program's spans reach
-    the profiler's host plane once per step, the compiled step's scopes
-    are found, and the tracer-cost windows run. (The CPU trace has no TPU
-    plane, so nothing reads device time here.)"""
-    import json
+def test_load_reads_the_trace_once(monkeypatch):
+    """One read of the file gives the benchmark's spans and the program's
+    apart, and the device events as ``trace_reduce.load`` gives them."""
+    import jax
+
+    path = str(BENCH / "testdata" / "trace_1chip.xplane.pb")
+    want = tr.load(path)
+    reads = []
+    from_file = jax.profiler.ProfileData.from_file
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        lambda p: reads.append(p) or from_file(p))
+    ev, spans = sr.load(path)
+    assert reads == [path]
+    assert ev == want and spans == []
+
+
+def test_idle_names_match_every_span():
+    """Gaps named among only the spans near each gap read as they do
+    among all the spans."""
+    rng = random.Random(2**31 + 5)
+    names = ("step", "batch", "train/run", "train/wait", "train/log")
+    for _ in range(20):
+        evs, t = [], 0.0
+        for _ in range(60):
+            t += rng.choice((0.0, 500.0, 3e3, 2e4))
+            d = rng.uniform(1e3, 5e4)
+            evs.append((BODY, t, d))
+            t += d
+        spans = [(rng.choice(names), s, rng.uniform(1.0, 2e5))
+                 for s in sorted(rng.uniform(-1e5, t) for _ in range(40))]
+        out = sr.idle_names({"/device:TPU:0": evs}, spans, (0.0, t))
+        by_span: dict = {}
+        for g in tr.gaps(tr.union((s, s + d) for _, s, d in evs), 0.0, t):
+            for n, secs in sr.innermost_time(g, spans).items():
+                by_span[n] = by_span.get(n, 0.0) + secs
+        assert out["idle_by_span"] == pytest.approx(by_span)
+
+
+def test_traced_run_on_cpu(monkeypatch):
+    """``bench/run.py``'s traced path at a tiny size: the step compiles
+    once, for its scope map, and that compile serves every call; the
+    program's spans give ``host_step_ms``; the run is correct. (The CPU
+    trace has no TPU plane, so no device metric is read.)"""
+    import time
 
     import jax
 
-    from bench.tests.test_bench_faults import SSM, tiny_cell
+    import bench.peaks
+    from bench import run
+    from bench.tests.test_bench_faults import tiny_cell
 
-    scope_trace = load("scope_trace", BENCH / "tools" / "scope_trace.py")
+    monkeypatch.setattr(bench.peaks, "peak",
+                        lambda kind: {"bf16_flops_per_s": 1.0})
+    compiled = []
+    on = run.CompileLog._on
 
-    wl, cfgf = tiny_cell(SSM, 1)
-    res = scope_trace.trace_seed("tiny", wl, cfgf, 2**31 + 17, 0.0, 1,
-                                 jax.devices()[:1], tmp_path)
-    n = res["steps"]
-    assert n >= 1
-    assert res["host_span_n"] == {"train/run": n, "train/batch": n,
-                                  "train/dispatch": n, "train/wait": n,
-                                  "train/log": n}
-    assert res["metrics"]["host_step_ms"] > 0
-    assert not {"model_ms", "compress_ms", "update_ms"} & set(res["metrics"])
-    assert [c["tracer"] for c in res["tracer_cost"]] == [False, True]
-    saved = json.loads((tmp_path / f"tiny.{2**31 + 17}.json").read_text())
-    assert {"model", "sync/compress", "update"} <= set(
-        saved["op_scope_counts"])
+    def count(self, event, duration, **kw):
+        on(self, event, duration, **kw)
+        if event == self.EVENT:
+            compiled.append(str(kw.get("fun_name")))
+
+    monkeypatch.setattr(run.CompileLog, "_on", count)
+    texts = []
+    step_text = run.step_text
+    monkeypatch.setattr(run, "step_text",
+                        lambda *a: texts.append(step_text(*a)) or texts[-1])
+    wl, cfgf = tiny_cell("mamba2", 1)
+    res = run.run_cell("tiny-traced", wl, cfgf, 2**31 + 23, 0.0, True,
+                       jax.devices()[:1], time.perf_counter())
+    assert res["correct"], res["checks"]
+    assert len(texts) == 1 and "op_name=" in texts[0]
+    assert compiled.count("jit(stepped)") == 1, compiled
+    assert res["metrics"]["host_step_ms"]["value"] > 0
+    assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}

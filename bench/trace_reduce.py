@@ -11,7 +11,7 @@ What is read:
   benchmark writes around its own calls (``step``, ``batch``,
   ``dispatch``, ``wait``), on whichever thread line they are.
 
-What comes out (``reduce_trace``), per device and averaged over devices:
+What comes out (``reduce_events``), per device and averaged over devices:
 
 * ``busy_s``: the union of the intervals in which an operation ran;
 * ``window_s``: the traced window, given by the caller as the host span
@@ -37,6 +37,7 @@ approximate.
 """
 from __future__ import annotations
 
+import functools
 import re
 from typing import Iterable
 
@@ -46,12 +47,15 @@ COLLECTIVE_RE = re.compile(
 HOST_SPANS = ("step", "batch", "dispatch", "wait")
 
 
+# a trace repeats each instruction's text once a step: parse each text once
+@functools.lru_cache(maxsize=None)
 def op_name(hlo_text: str) -> str:
     """``%fusion.3 = bf16[...] fusion(...)`` -> ``fusion.3``."""
     head = hlo_text.split(" = ", 1)[0]
     return head.lstrip("%").strip()
 
 
+@functools.lru_cache(maxsize=None)
 def op_code(hlo_text: str) -> str:
     """The HLO opcode of an ``XLA Ops`` event (``fusion``, ``all-gather``,
     ...); the instruction name when the text has no ``=``."""
@@ -73,6 +77,7 @@ def op_code(hlo_text: str) -> str:
     return m.group(1) if m else op_name(hlo_text)
 
 
+@functools.lru_cache(maxsize=None)
 def is_collective(hlo_text: str) -> bool:
     return bool(COLLECTIVE_RE.match(op_code(hlo_text))
                 or COLLECTIVE_RE.match(op_name(hlo_text)))
@@ -131,10 +136,11 @@ def gaps(busy, lo: float, hi: float) -> list[tuple[float, float]]:
             if g[1] - g[0] >= MIN_GAP_NS]
 
 
-def load(path: str) -> dict:
+def load(path: str, spans: tuple[str, ...] = HOST_SPANS) -> dict:
     """Events of a trace file: {"devices": {name: [(text, start_ns,
     dur_ns)]}, "async": {name: [...]}, "host": [(name, start_ns, dur_ns)]}
-    with host spans limited to the benchmark's annotations."""
+    with host spans limited to those named in ``spans`` (the benchmark's
+    annotations)."""
     import jax
 
     pd = jax.profiler.ProfileData.from_file(path)
@@ -153,7 +159,7 @@ def load(path: str) -> dict:
             # ("python", "python3"): take the spans from any line
             for line in plane.lines:
                 host.extend((e.name, float(e.start_ns), float(e.duration_ns))
-                            for e in line.events if e.name in HOST_SPANS)
+                            for e in line.events if e.name in spans)
     return {"devices": devices, "async": asyncs, "host": host}
 
 
@@ -247,8 +253,3 @@ def host_window(ev: dict, name: str = "step") -> tuple[float, float] | None:
     if not spans:
         return None
     return min(s for s, _ in spans), max(e for _, e in spans)
-
-
-def reduce_trace(path: str, top: int = 10) -> dict:
-    ev = load(path)
-    return reduce_events(ev, host_window(ev), top=top)
